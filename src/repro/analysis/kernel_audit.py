@@ -118,6 +118,9 @@ class Capture:
     grid: Tuple[int, ...]
     in_pairs: List[Tuple[object, Tuple[int, ...]]]
     out_pairs: List[Tuple[object, Tuple[int, ...]]]
+    # Scalar-prefetch operands (``PrefetchScalarGridSpec``): they carry no
+    # BlockSpec, and every index map takes them as trailing arguments.
+    prefetch: List[np.ndarray] = dataclasses.field(default_factory=list)
 
 
 def _as_list(x):
@@ -138,7 +141,13 @@ def capture_pallas_calls(fn, args: Sequence, kwargs: Optional[Dict] = None
     real = pl.pallas_call
 
     def fake(kernel, out_shape=None, *, grid=(), in_specs=None,
-             out_specs=None, **_kw):
+             out_specs=None, grid_spec=None, **_kw):
+        nsp = 0
+        if grid_spec is not None:
+            grid, in_specs = grid_spec.grid, grid_spec.in_specs
+            out_specs = grid_spec.out_specs
+            nsp = getattr(grid_spec, "num_scalar_prefetch", 0)
+
         def runner(*operands):
             outs = _as_list(out_shape)
             records.append(Capture(
@@ -148,9 +157,11 @@ def capture_pallas_calls(fn, args: Sequence, kwargs: Optional[Dict] = None
                 grid=tuple(grid) if isinstance(grid, (tuple, list))
                 else (int(grid),),
                 in_pairs=list(zip(_as_list(in_specs),
-                                  [tuple(o.shape) for o in operands])),
+                                  [tuple(o.shape) for o in operands[nsp:]])),
                 out_pairs=list(zip(_as_list(out_specs),
                                    [tuple(s.shape) for s in outs])),
+                prefetch=[np.zeros(o.shape, o.dtype)
+                          for o in operands[:nsp]],
             ))
             zeros = [jnp.zeros(s.shape, s.dtype) for s in outs]
             return tuple(zeros) if isinstance(out_shape, (tuple, list)) \
@@ -170,7 +181,8 @@ def capture_pallas_calls(fn, args: Sequence, kwargs: Optional[Dict] = None
 # --------------------------------------------------------------------------
 
 def _check_pair(spec, shape: Tuple[int, ...], grid: Tuple[int, ...],
-                role: str, where: str) -> List[Issue]:
+                role: str, where: str, prefetch: Sequence = ()
+                ) -> List[Issue]:
     issues: List[Issue] = []
     if spec is None:                       # whole-array mapping: trivially
         return issues                      # in bounds
@@ -195,7 +207,7 @@ def _check_pair(spec, shape: Tuple[int, ...], grid: Tuple[int, ...],
                                   for g in grid])
     for corner in corners:
         try:
-            idx = spec.index_map(*corner)
+            idx = spec.index_map(*corner, *prefetch)
         except Exception as e:
             issues.append(Issue(
                 "kernel_geometry", where,
@@ -228,10 +240,10 @@ def check_capture_geometry(cap: Capture, where: str) -> List[Issue]:
     issues: List[Issue] = []
     for k, (spec, shape) in enumerate(cap.in_pairs):
         issues.extend(_check_pair(spec, shape, cap.grid,
-                                  f"in_specs[{k}]", where))
+                                  f"in_specs[{k}]", where, cap.prefetch))
     for k, (spec, shape) in enumerate(cap.out_pairs):
         issues.extend(_check_pair(spec, shape, cap.grid,
-                                  f"out_specs[{k}]", where))
+                                  f"out_specs[{k}]", where, cap.prefetch))
     return issues
 
 
@@ -529,7 +541,7 @@ def _body_cases() -> List[Dict]:
                    _sds((npages, ps, hk, 1), f16),
                    _sds((npages, ps), i32), _sds((b, npg), i32),
                    _sds((b, s), i32)),
-             static={"window": None, "block_t": ps}),
+             static={"window": None}),
     ]
 
 

@@ -15,8 +15,9 @@ Measurement (:func:`autotune_op`) is explicit and happens *outside* any
 trace: benchmarks (``runtime_proxy.py --autotune``) or an operator's
 one-off script time each candidate with ``block_until_ready`` and persist
 the winner. The cache location is ``$SONIQ_AUTOTUNE_CACHE`` (a file path)
-or ``~/.cache/soniq/autotune.json``; nothing is ever written unless a
-measurement runs.
+or ``benchmarks/autotune.json`` inside the checkout, which may be absent
+(every kernel then runs its static defaults); nothing is ever written
+unless a measurement runs.
 """
 from __future__ import annotations
 
@@ -49,21 +50,22 @@ DEFAULT_BLOCKS: Dict[str, Dict[str, int]] = {
     "noise_inject": {"block_k": 256, "block_n": 256},
     "fake_quant": {"block_m": 256, "block_k": 256},
     # Quantized-KV flash decode: shape key is (query rows B*Hk*S*G, ring
-    # length T, head_dim D); block_t tiles the ring inner loop.
+    # length T, head_dim D); block_t tiles the ring inner loop. (The paged
+    # kernel has no knob: its tile is one page.)
     "qkv_attn_decode": {"block_t": 256},
-    # Paged flash decode: shape key is (query rows B*Hk*S*G, table length
-    # NP, page_size, head_dim D); block_t tiles *within* a page, so it is
-    # snapped to a divisor of page_size.
-    "qkv_attn_decode_paged": {"block_t": 128},
 }
 
 _CACHE: Optional[Dict[str, Dict]] = None
 _CACHE_FILE: Optional[str] = None
 
 
+# <checkout>/benchmarks/autotune.json (this file is src/repro/backend/).
+DEFAULT_CACHE = Path(__file__).resolve().parents[3] / "benchmarks" \
+    / "autotune.json"
+
+
 def cache_path() -> Path:
-    return Path(os.environ.get(ENV_CACHE)
-                or Path.home() / ".cache" / "soniq" / "autotune.json")
+    return Path(os.environ.get(ENV_CACHE) or DEFAULT_CACHE)
 
 
 def cache_key(op: str, shape: Sequence[int], p: int, dtype,
@@ -156,41 +158,34 @@ def _divisor_candidates(total: int, multiple: int,
 
 
 def candidates_for(op: str, shape: Sequence[int]) -> List[Dict[str, int]]:
-    """A small grid of legal block configs for ``op`` at ``shape``
-    (divisor-snapped, so every candidate tiles exactly)."""
-    from repro.core.qtypes import GROUP_SIZE
-    if op in ("packed_segment_matmul", "fused_act_segment_matmul"):
-        m, kp, n = shape
+    """A small grid of legal block configs for ``op`` at ``shape``: each
+    block divides its dim and is tile-aligned (8 sublanes, 128 lanes) or
+    the whole dim, the rule Mosaic lowers."""
+    from repro.kernels.packed_matmul import LANE, SUBLANE
+    if op not in DEFAULT_BLOCKS:
+        return [{}]                    # no block knob: the defaults only
+    rows = (64, 128, 256, 512)
+    if op in ("packed_segment_matmul", "fused_act_segment_matmul",
+              "packed_segment_matmul_grouped"):
+        m, kp, n = shape[-3:]
         return [{"block_m": bm, "block_n": bn, "block_k": bk}
-                for bm in _divisor_candidates(m, 1, (64, 128, 256, 512))
-                for bn in _divisor_candidates(n, 1, (128, 256))
-                for bk in _divisor_candidates(kp, GROUP_SIZE,
-                                              (128, 256, 512))]
-    if op == "packed_segment_matmul_grouped":
-        _e, m, kp, n = shape
-        return [{"block_m": bm, "block_n": bn, "block_k": bk}
-                for bm in _divisor_candidates(m, 1, (64, 128, 256, 512))
-                for bn in _divisor_candidates(n, 1, (128, 256))
-                for bk in _divisor_candidates(kp, GROUP_SIZE,
-                                              (128, 256, 512))]
+                for bm in _divisor_candidates(m, SUBLANE, rows)
+                for bn in _divisor_candidates(n, LANE, (128, 256))
+                for bk in _divisor_candidates(kp, LANE, (128, 256, 512))]
     if op == "fake_quant":
         m, k = shape
         return [{"block_m": bm, "block_k": bk}
-                for bm in _divisor_candidates(m, 1, (64, 128, 256, 512))
-                for bk in _divisor_candidates(k, GROUP_SIZE,
-                                              (128, 256, 512))]
+                for bm in _divisor_candidates(m, SUBLANE, rows)
+                for bk in _divisor_candidates(k, LANE, (128, 256, 512))]
     if op == "qkv_attn_decode":
         _m, t, _d = shape
         return [{"block_t": bt}
-                for bt in _divisor_candidates(t, 1, (128, 256, 512, 1024))]
-    if op == "qkv_attn_decode_paged":
-        _m, _np, ps, _d = shape
-        return [{"block_t": bt}
-                for bt in _divisor_candidates(ps, 1, (8, 16, 32, 64, 128))]
+                for bt in _divisor_candidates(t, LANE,
+                                              (128, 256, 512, 1024))]
     k, n = shape
     return [{"block_k": bk, "block_n": bn}
-            for bk in _divisor_candidates(k, GROUP_SIZE, (128, 256, 512))
-            for bn in _divisor_candidates(n, 1, (128, 256, 512))]
+            for bk in _divisor_candidates(k, LANE, (128, 256, 512))
+            for bn in _divisor_candidates(n, LANE, (128, 256, 512))]
 
 
 def measure(fn, iters: int = 3) -> float:

@@ -44,6 +44,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core import pack as pack_lib
 from repro.core import quant
@@ -126,6 +127,30 @@ def hash_eps(shape: Tuple[int, ...], seed):
     idx = (jnp.arange(k, dtype=jnp.uint32)[:, None] * jnp.uint32(n)
            + jnp.arange(n, dtype=jnp.uint32)[None, :])
     return prng.uniform_pm1(idx, seed)
+
+
+def over_mesh_rows(kernel, *args, rows: Tuple[bool, ...]):
+    """Call ``kernel(*args)`` so that it runs under a sharded jit.
+
+    The compiler cannot partition a Mosaic kernel: inside a jit over a
+    mesh (``jax.set_mesh``, as the sharded train step runs) it refuses
+    one whose operands are sharded. Under an ambient mesh this runs the
+    kernel in ``shard_map``, each device on its own block of rows: operand
+    ``i`` is split on its first dim across every mesh axis where
+    ``rows[i]``, replicated otherwise, and the output is split like the
+    row operands. That is right for a kernel that treats rows
+    independently (``fake_quant``). Where the rows do not divide over the
+    mesh, or no operand is row-split, every device runs the whole call.
+    Without a mesh the kernel is called directly."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return kernel(*args)
+    split = any(rows) and all(a.shape[0] % mesh.size == 0
+                              for a, r in zip(args, rows) if r)
+    row = P(tuple(mesh.axis_names)) if split else P()
+    return jax.shard_map(
+        kernel, in_specs=tuple(row if r else P() for r in rows),
+        out_specs=row, check_vma=False)(*args)
 
 
 # --------------------------------------------------------------------------
@@ -344,9 +369,10 @@ class Backend:
         it.
 
         The base implementation is the two-pass reference composition —
-        bit-exact with a fused kernel by construction, since fusion only
-        removes the HBM round-trip of the quantized activations (and, for
-        the self-scale form, of the [M, 1] reduction), not any arithmetic.
+        equal to a fused kernel up to the lowering of the prologue's f32
+        divide (a few ulp of the output scale), since fusion only removes
+        the HBM round-trip of the quantized activations (and, for the
+        self-scale form, of the [M, 1] reduction), not any arithmetic.
         Backends that carry a real fused kernel override this; the shared
         ``packed_matmul`` driver only takes the fused path when they do
         (``supports("fused_act_segment_matmul")``)."""
@@ -425,8 +451,8 @@ class Backend:
         The driver is shared so every backend applies *identical*
         activation scaling (the whole-batch-abs-max magnitude leak the
         old kernel wrapper had cannot reappear per-backend) and identical
-        segment/accumulation order. Activation quantization has two
-        bit-exact forms (DESIGN.md §11 "Fused activation quantization"):
+        segment/accumulation order. Activation quantization has two forms,
+        equal to a few ulp (DESIGN.md §11 "Fused activation quantization"):
         the two-pass reference (one whole-K ``fake_quant``, then plain
         segment GEMMs — what ``xla_ref`` always runs) and the fused form
         (the epsilon-clamped per-token scale is still computed here, since
@@ -472,8 +498,8 @@ class Backend:
             # under per-token scaling: the [M, K] -> [M, 1] abs-max moves
             # into the fused kernel's prologue too (it no longer crosses a
             # segment boundary). The abs-max is permutation-invariant, so
-            # in-kernel reduction over the permuted row is bit-identical
-            # to the driver-side scale (DESIGN.md §11).
+            # in-kernel reduction over the permuted row matches the
+            # driver-side scale to an ulp (DESIGN.md §11).
             self_scale = (fused and qcfg.act_scale_mode == "per_token"
                           and len(segs) == 1 and segs[0][3] == k)
             if not self_scale:

@@ -12,9 +12,9 @@ TPU. Selection between them is a registry concern ("pallas" alias);
 
 Geometry the kernels cannot express (a K narrower than the 16-channel
 group, carrier rows that do not tile) falls back per-call to the jnp
-reference math — which is numerically *identical* for these ops (integer
-pack outputs, hash-exact noise), so the fallback is invisible; it is a
-shape-coverage escape hatch, not a different answer.
+reference math. Every fallback is counted per op
+(:func:`fallback_counts`), beside the kernel dispatch counts, so a run
+can show that it took none.
 
 Block shapes come from :mod:`repro.backend.autotune`: an on-disk cache
 keyed by (op, shape, dtype, platform), falling back to the static defaults
@@ -24,7 +24,10 @@ trace); measurement is explicit (``autotune.autotune_op`` /
 """
 from __future__ import annotations
 
+import functools
 import importlib
+from collections import Counter
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -41,60 +44,61 @@ _fq = importlib.import_module("repro.kernels.fake_quant")
 _ad = importlib.import_module("repro.kernels.attn_decode")
 
 from . import autotune
-from .base import Backend
+from .base import Backend, over_mesh_rows
 from .registry import register
 from .xla_ref import XLA_REF as _REF   # per-call geometry fallback
 
-# Trace-time dispatch counters for the fused kernel paths. CI's
-# SONIQ_BACKEND=pallas_interpret leg asserts the serve driver actually
-# engaged the fused activation-quant prologue (not the jnp fallback).
-_FUSED_ACT_CALLS = 0
-_FAKE_QUANT_KERNEL_CALLS = 0
-_QKV_ATTN_CALLS = 0
-_QKV_PAGED_CALLS = 0
-_GROUPED_MATMUL_CALLS = 0
+# Trace-time dispatch counters, per op: how often a Pallas backend traced
+# its kernel, and how often it gave way to the jnp reference math for
+# geometry the kernel cannot express. CI's SONIQ_BACKEND=pallas_interpret
+# leg and the chip smoke assert that the serve path engaged the kernels
+# and took no fallback.
+_KERNEL_CALLS: Counter = Counter()
+_FALLBACKS: Counter = Counter()
+
+
+def kernel_call_counts() -> Dict[str, int]:
+    """Kernel dispatches per op, counted at trace time (not per step)."""
+    return dict(_KERNEL_CALLS)
+
+
+def fallback_counts() -> Dict[str, int]:
+    """Reference-math fallbacks per op, counted at trace time."""
+    return dict(_FALLBACKS)
 
 
 def fused_act_call_count() -> int:
-    """How many times a Pallas backend dispatched the fused activation-
-    quant GEMM kernel (counted at trace time, not per executed step)."""
-    return _FUSED_ACT_CALLS
+    """Dispatches of the fused activation-quant GEMM kernel (either
+    prologue form)."""
+    return _KERNEL_CALLS["fused_act_segment_matmul"]
 
 
 def grouped_matmul_call_count() -> int:
-    """How many times a Pallas backend dispatched the grouped
-    (stacked-expert) segment-GEMM kernel (vs the jnp geometry fallback) —
-    counted at trace time; CI's pallas_interpret leg asserts the MoE serve
-    path actually engaged the grouped kernel, not the vmap fallback."""
-    return _GROUPED_MATMUL_CALLS
+    """Dispatches of the grouped (stacked-expert) segment-GEMM kernel."""
+    return _KERNEL_CALLS["packed_segment_matmul_grouped"]
 
 
 def fake_quant_kernel_call_count() -> int:
-    """How many times a Pallas backend dispatched the fused fake_quant
-    forward kernel (vs the jnp geometry fallback)."""
-    return _FAKE_QUANT_KERNEL_CALLS
+    """Dispatches of the fused fake_quant forward kernel."""
+    return _KERNEL_CALLS["fake_quant"]
 
 
 def qkv_attn_call_count() -> int:
-    """How many times a Pallas backend dispatched the fused quantized-KV
-    flash-decode kernel (vs the dequantize-everything jnp fallback) —
-    counted at trace time; CI's pallas_interpret leg asserts the q4 serve
-    path actually engaged the kernel (DESIGN.md §12)."""
-    return _QKV_ATTN_CALLS
+    """Dispatches of the ring quantized-KV flash-decode kernel
+    (DESIGN.md §12)."""
+    return _KERNEL_CALLS["qkv_attn_decode"]
 
 
 def qkv_attn_paged_call_count() -> int:
-    """How many times a Pallas backend dispatched the paged flash-decode
-    kernel (page-table walk + online softmax, DESIGN.md §13) vs the dense
-    gather oracle — counted at trace time; CI's paged leg asserts the
-    paged serve path actually engaged the kernel, not the fallback."""
-    return _QKV_PAGED_CALLS
+    """Dispatches of the paged flash-decode kernel (DESIGN.md §13)."""
+    return _KERNEL_CALLS["qkv_attn_decode_paged"]
 
 
 class PallasBackend(Backend):
-    """Shared Pallas plumbing; ``interpret`` picks the execution mode."""
+    """Shared Pallas plumbing; ``interpret`` (set by each subclass) picks
+    the execution mode."""
 
-    interpret: bool = True
+    interpret: bool
 
     def _blocks(self, op: str, shape, p, dtype, blocks):
         """Explicit caller blocks win; else the autotune cache; else the
@@ -109,9 +113,11 @@ class PallasBackend(Backend):
                               group_size: int = GROUP_SIZE, **blocks):
         if group_size != GROUP_SIZE or x.ndim != 2 \
                 or x.shape[1] == 0 or x.shape[1] % GROUP_SIZE:
+            _FALLBACKS["packed_segment_matmul"] += 1
             return _REF.packed_segment_matmul(
                 x, wp, scales, p=p, act_quant=act_quant,
                 group_size=group_size)
+        _KERNEL_CALLS["packed_segment_matmul"] += 1
         m, kp = x.shape
         blocks = self._blocks("packed_segment_matmul", (m, kp, wp.shape[1]),
                               p, x.dtype, blocks)
@@ -126,11 +132,11 @@ class PallasBackend(Backend):
         if group_size != GROUP_SIZE or x.ndim != 3 \
                 or x.shape[2] == 0 or x.shape[2] % GROUP_SIZE \
                 or x.shape[0] == 0 or x.shape[1] == 0:
+            _FALLBACKS["packed_segment_matmul_grouped"] += 1
             return _REF.packed_segment_matmul_grouped(
                 x, wp, scales, p=p, act_quant=act_quant,
                 group_size=group_size)
-        global _GROUPED_MATMUL_CALLS
-        _GROUPED_MATMUL_CALLS += 1
+        _KERNEL_CALLS["packed_segment_matmul_grouped"] += 1
         e, m, kp = x.shape
         blocks = self._blocks("packed_segment_matmul_grouped",
                               (e, m, kp, wp.shape[2]), p, x.dtype, blocks)
@@ -143,11 +149,11 @@ class PallasBackend(Backend):
                                  in_kernel_scale: bool = False, **blocks):
         if group_size != GROUP_SIZE or x.ndim != 2 \
                 or x.shape[1] == 0 or x.shape[1] % GROUP_SIZE:
+            _FALLBACKS["fused_act_segment_matmul"] += 1
             return _REF.fused_act_segment_matmul(
                 x, wp, scales, act_scales, p=p, group_size=group_size,
                 in_kernel_scale=in_kernel_scale)
-        global _FUSED_ACT_CALLS
-        _FUSED_ACT_CALLS += 1
+        _KERNEL_CALLS["fused_act_segment_matmul"] += 1
         m, kp = x.shape
         blocks = self._blocks("fused_act_segment_matmul",
                               (m, kp, wp.shape[1]), p, x.dtype, blocks)
@@ -173,9 +179,9 @@ class PallasBackend(Backend):
         kc = cache["k_codes"]
         if d % 2 or kc.ndim != 4 or kc.shape != (b, kc.shape[1], hk, d // 2) \
                 or kc.shape[1] == 0:
+            _FALLBACKS["qkv_attn_decode"] += 1
             return _REF.qkv_attn_decode(q, cache, q_pos, window=window)
-        global _QKV_ATTN_CALLS
-        _QKV_ATTN_CALLS += 1
+        _KERNEL_CALLS["qkv_attn_decode"] += 1
         t = kc.shape[1]
         blocks = self._blocks("qkv_attn_decode", (b * hk * s * g, t, d),
                               4, q.dtype, blocks)
@@ -184,37 +190,33 @@ class PallasBackend(Backend):
             cache["pos"], q_pos, window=window, interpret=self.interpret,
             **blocks)
 
-    def qkv_attn_decode_paged(self, q, cache, q_pos, *, window=None,
-                              **blocks):
+    def qkv_attn_decode_paged(self, q, cache, q_pos, *, window=None):
         """Paged flash-decode (kernels/attn_decode.py): walks the slot's
         page table over the global pool with an online softmax — no dense
         gather, no [SG, T] score row. The kernel covers the packed-q4
         pool; the fp paged family and geometry the kernel cannot express
         (odd head_dim, empty pool) fall back to the gather oracle."""
-        b, s, hk, g, d = q.shape
+        _b, _s, hk, _g, d = q.shape
         kc = cache.get("k_codes")
-        npg = cache["page_table"].shape[-1]
         if kc is None or d % 2 or kc.ndim != 4 \
                 or kc.shape[2:] != (hk, d // 2) or kc.shape[0] == 0 \
                 or kc.shape[1] == 0:
+            _FALLBACKS["qkv_attn_decode_paged"] += 1
             return _REF.qkv_attn_decode_paged(q, cache, q_pos,
                                               window=window)
-        global _QKV_PAGED_CALLS
-        _QKV_PAGED_CALLS += 1
-        npages, ps = kc.shape[0], kc.shape[1]
-        blocks = self._blocks("qkv_attn_decode_paged",
-                              (b * hk * s * g, npg, ps, d), 4, q.dtype,
-                              blocks)
+        _KERNEL_CALLS["qkv_attn_decode_paged"] += 1
         return _ad.qkv_attn_decode_paged(
             q, kc, cache["v_codes"], cache["k_scale"], cache["v_scale"],
             cache["pos"], cache["page_table"], q_pos, window=window,
-            interpret=self.interpret, **blocks)
+            interpret=self.interpret)
 
     def quantize_pack(self, w, scales=None, *, p: int,
                       group_size: int = GROUP_SIZE, **blocks):
         if group_size != GROUP_SIZE or w.ndim != 2 \
                 or w.shape[0] % GROUP_SIZE:
+            _FALLBACKS["quantize_pack"] += 1
             return _REF.quantize_pack(w, scales, p=p, group_size=group_size)
+        _KERNEL_CALLS["quantize_pack"] += 1
         blocks = self._blocks("quantize_pack", tuple(w.shape), p, w.dtype,
                               blocks)
         return _qp.quantize_pack(w, scales, p=p, interpret=self.interpret,
@@ -223,11 +225,17 @@ class PallasBackend(Backend):
     def _noise_inject_fwd(self, w, s, seed, group_size, blocks):
         if group_size != GROUP_SIZE or w.ndim != 2 \
                 or w.shape[0] % GROUP_SIZE:
+            _FALLBACKS["noise_inject"] += 1
             return super()._noise_inject_fwd(w, s, seed, group_size, blocks)
+        _KERNEL_CALLS["noise_inject"] += 1
         blocks = self._blocks("noise_inject", tuple(w.shape), 0, w.dtype,
                               blocks)
-        return _ni.noise_inject(w, s, seed, interpret=self.interpret,
-                                **blocks)
+        # ε hashes the global element index, so under a mesh every device
+        # runs the whole tensor (no operand is row-split).
+        kern = functools.partial(_ni.noise_inject, interpret=self.interpret,
+                                 **blocks)
+        return over_mesh_rows(kern, w, s, jnp.asarray(seed, jnp.uint32),
+                              rows=(False, False, False))
 
     def _fake_quant_fwd(self, x, pbits, scale, group_size):
         """Fused QAT quantize-dequantize forward. Falls back to the jnp
@@ -239,11 +247,13 @@ class PallasBackend(Backend):
         if (group_size != GROUP_SIZE or x.ndim < 1 or k == 0
                 or k % GROUP_SIZE or pb.ndim != 1
                 or pb.shape[0] * GROUP_SIZE != k):
+            _FALLBACKS["fake_quant"] += 1
             return super()._fake_quant_fwd(x, pbits, scale, group_size)
         ng = k // GROUP_SIZE
         lead = x.shape[:-1]
         m = int(np.prod(lead, dtype=np.int64)) if lead else 1
         if m == 0:
+            _FALLBACKS["fake_quant"] += 1
             return super()._fake_quant_fwd(x, pbits, scale, group_size)
         s = jnp.asarray(scale, jnp.float32)
         if s.ndim == 0 or (s.shape[-1] == 1
@@ -254,12 +264,14 @@ class PallasBackend(Backend):
         elif s.ndim == 1 and s.shape[0] == ng:
             s_op, row = s, False
         else:
+            _FALLBACKS["fake_quant"] += 1
             return super()._fake_quant_fwd(x, pbits, scale, group_size)
-        global _FAKE_QUANT_KERNEL_CALLS
-        _FAKE_QUANT_KERNEL_CALLS += 1
+        _KERNEL_CALLS["fake_quant"] += 1
         blocks = self._blocks("fake_quant", (m, k), 0, x.dtype, {})
-        y2 = _fq.fake_quant(x.reshape(m, k), pb, s_op, row_scale=row,
-                            interpret=self.interpret, **blocks)
+        kern = functools.partial(_fq.fake_quant, row_scale=row,
+                                 interpret=self.interpret, **blocks)
+        y2 = over_mesh_rows(kern, x.reshape(m, k), pb, s_op,
+                            rows=(True, False, row))
         return y2.reshape(x.shape)
 
 

@@ -77,7 +77,8 @@ class QuantConfig:
 
     # Let a backend that carries a fused activation-quant GEMM prologue
     # (``fused_act_segment_matmul``) use it on the serve path. The fused
-    # and two-pass forms are bit-exact (DESIGN.md §11), so this stays on;
+    # and two-pass forms agree to a few ulp (DESIGN.md §11), so this stays
+    # on;
     # False forces the two-pass reference form everywhere — benchmarks use
     # it to measure the fusion delta, parity tests to pin the exactness.
     fuse_act_quant: bool = True

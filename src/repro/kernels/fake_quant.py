@@ -24,15 +24,10 @@ from jax.experimental import pallas as pl
 from repro.core.qtypes import GROUP_SIZE
 
 
-def _kernel(x_ref, pb_ref, s_ref, o_ref, *, row_scale: bool):
+def _kernel(x_ref, pb_ref, s_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)
-    p = jnp.repeat(pb_ref[...].astype(jnp.float32), GROUP_SIZE,
-                   axis=1)                                  # [1, bk]
-    if row_scale:
-        s = s_ref[...].astype(jnp.float32)                  # [bm, 1]
-    else:
-        s = jnp.repeat(s_ref[...].astype(jnp.float32), GROUP_SIZE,
-                       axis=1)                              # [1, bk]
+    p = pb_ref[...]                       # [1, bk] per-channel precision
+    s = s_ref[...]                        # [bm, 1] per row or [1, bk]
     xs = x / s
     h = jnp.exp2(1.0 - p)                 # 2^(1-p): half-step
     two_p = 2.0 / h                       # 2^p
@@ -44,34 +39,39 @@ def _kernel(x_ref, pb_ref, s_ref, o_ref, *, row_scale: bool):
 
 @functools.partial(jax.jit, static_argnames=(
     "row_scale", "block_m", "block_k", "interpret"))
-def fake_quant(x, pbits, scale, *, row_scale: bool, block_m: int = 256,
-               block_k: int = 256, interpret: bool = True):
+def fake_quant(x, pbits, scale, *, row_scale: bool, interpret: bool,
+               block_m: int = 256, block_k: int = 256):
     """x [M, K], pbits [K//16] -> quantize-dequantized x (same dtype).
 
     ``scale`` is [M, 1] when ``row_scale`` (per-token activation scaling)
-    else [K//16] (per-group weight scaling).
+    else [K//16] (per-group weight scaling). Per-group operands are
+    expanded to one value per channel here, so their blocks are lane-dense
+    [1, bk] rows that Mosaic tiles like x's.
     """
-    from .packed_matmul import fit_block
+    from .packed_matmul import LANE, SUBLANE, fit_block
     m, k = x.shape
-    bm = fit_block(m, block_m)
-    bk = fit_block(k, block_k, GROUP_SIZE)
-    pb2 = jnp.asarray(pbits, jnp.float32).reshape(1, -1)
+    bm = fit_block(m, block_m, SUBLANE)
+    bk = fit_block(k, block_k, LANE)
+
+    def per_channel(v):
+        return jnp.repeat(jnp.asarray(v, jnp.float32), GROUP_SIZE,
+                          total_repeat_length=k).reshape(1, k)
+
+    k_spec = pl.BlockSpec((1, bk), lambda i, j: (0, j))
     if row_scale:
         s_op = jnp.asarray(scale, jnp.float32).reshape(m, 1)
         s_spec = pl.BlockSpec((bm, 1), lambda i, j: (i, 0))
     else:
-        s_op = jnp.asarray(scale, jnp.float32).reshape(1, -1)
-        s_spec = pl.BlockSpec((1, bk // GROUP_SIZE), lambda i, j: (0, j))
-    kern = functools.partial(_kernel, row_scale=row_scale)
+        s_op, s_spec = per_channel(scale), k_spec
     return pl.pallas_call(
-        kern,
+        _kernel,
         grid=(m // bm, k // bk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j: (i, j)),
-            pl.BlockSpec((1, bk // GROUP_SIZE), lambda i, j: (0, j)),
+            k_spec,
             s_spec,
         ],
         out_specs=pl.BlockSpec((bm, bk), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, k), x.dtype),
         interpret=interpret,
-    )(x, pb2, s_op)
+    )(x, per_channel(pbits), s_op)

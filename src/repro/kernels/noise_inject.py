@@ -37,13 +37,13 @@ def _kernel(seed_ref, w_ref, s_ref, o_ref, *, bk: int, bn: int, n_total: int):
 
 @functools.partial(jax.jit, static_argnames=("block_k", "block_n",
                                              "interpret"))
-def noise_inject(w, s, seed, *, block_k: int = 256, block_n: int = 256,
-                 interpret: bool = True):
+def noise_inject(w, s, seed, *, interpret: bool, block_k: int = 256,
+                 block_n: int = 256):
     """w [K, N], s [K//16] -> perturbed + clipped w (same dtype as w)."""
-    from .packed_matmul import fit_block
+    from .packed_matmul import LANE, fit_block
     k, n = w.shape
-    bk = fit_block(k, block_k, GROUP_SIZE)
-    bn = fit_block(n, block_n)
+    bk = fit_block(k, block_k, LANE)   # bk//16 scale rows: a multiple of 8
+    bn = fit_block(n, block_n, LANE)
     s2d = jnp.asarray(s, jnp.float32).reshape(-1, 1)
     seed_arr = jnp.asarray([seed], jnp.uint32)
     kern = functools.partial(_kernel, bk=bk, bn=bn, n_total=n)

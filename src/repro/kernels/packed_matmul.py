@@ -26,7 +26,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -41,31 +40,37 @@ def _tpu_compiler_params(grid_rank: int = 3):
     Mosaic may not reorder/parallelize it; every outer dim (M/N tiles, and
     the expert dim of the grouped kernel) is 'parallel'. Ignored in
     interpret mode."""
-    # The class was renamed TPUCompilerParams -> CompilerParams across jax
-    # releases; accept either spelling.
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=("parallel",) * (grid_rank - 1)
-               + ("arbitrary",))
+    return pltpu.CompilerParams(dimension_semantics=("parallel",)
+                                * (grid_rank - 1) + ("arbitrary",))
+
+
+# Mosaic tiles the last two dims of every block by (8, 128); a block dim
+# must be a multiple of that tile or the whole array dim. K sits on the
+# lanes of the x block, so K blocks are multiples of 128 (which also keeps
+# the per-group scale block bk//16 a multiple of 8).
+SUBLANE, LANE = 8, 128
 
 
 def fit_block(total: int, want: int, multiple: int = 1) -> int:
-    """Largest divisor of ``total`` that is <= want and a multiple of
-    ``multiple`` (segment sizes are only guaranteed multiples of the
-    16-channel group, not of the preferred MXU tile)."""
-    want = min(want, total)
-    for d in range(want, multiple - 1, -1):
-        if total % d == 0 and d % multiple == 0:
+    """Largest divisor of ``total`` that is <= ``want`` and a multiple of
+    ``multiple``, or ``total`` itself when no such divisor exists. With
+    ``multiple`` = SUBLANE (second-minor dims) or LANE (minor dims) the
+    result is always a legal Mosaic block: tile-aligned or the full dim."""
+    d = min(want, total) // multiple * multiple
+    while d > 0:
+        if total % d == 0:
             return d
-    assert total % multiple == 0, (total, multiple)
-    return multiple
+        d -= multiple
+    return total
 
 
 def _unpack_dequant(wp, p: int, bk: int):
-    """[bk*p//8, bn] uint8 -> [bk, bn] f32 on the SMOL grid (no scale)."""
+    """[bk*p//8, bn] uint8 -> [bk, bn] f32 on the SMOL grid (no scale).
+    The codes widen to int32 first: Mosaic has no uint8 -> f32 cast."""
     vpb = 8 // p
-    mask = np.uint8((1 << p) - 1)
-    parts = [((wp >> np.uint8(p * j)) & mask) for j in range(vpb)]
+    w = wp.astype(jnp.int32)
+    mask = (1 << p) - 1
+    parts = [(w >> (p * j)) & mask for j in range(vpb)]
     u = jnp.stack(parts, axis=1).reshape(bk, wp.shape[-1])
     u = u.astype(jnp.float32)
     return (2.0 * u - float(2 ** p - 1)) * float(2.0 ** (1 - p))
@@ -130,18 +135,15 @@ def _fused_selfscale_kernel(x_ref, wp_ref, s_ref, o_ref, *, p: int,
     need the reduction across kernel invocations (DESIGN.md §11), which is
     exactly why the multi-segment form keeps the scale in the driver.
 
-    Mirrors ``core.quant.abs_max_scale`` element-for-element (fp32 abs-max
-    over the full row, ``ACT_SCALE_EPS`` clamp, divide by the 4-bit grid
-    top 1.875) so it is bit-exact with the driver-scale form."""
+    Mirrors ``core.quant.abs_max_scale`` (fp32 abs-max over the full row,
+    ``ACT_SCALE_EPS`` clamp, divide by the 4-bit grid top 1.875). A
+    compiler may lower that divide by a constant as a reciprocal multiply,
+    so the scale matches the driver-side one to an ulp, not bitwise."""
     x = x_ref[...].astype(jnp.float32)                  # [bm, K] full row
     m = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
-    # The barrier keeps the compiler from strength-reducing the division
-    # by the constant grid top into a reciprocal multiply (1-ulp off),
-    # which would break bitwise parity with the driver-side act_scale.
-    grid_top = jax.lax.optimization_barrier(jnp.float32(_GRID_TOP_4))
-    sx = jnp.maximum(m, ACT_SCALE_EPS) / grid_top       # [bm, 1]
-    xk = jax.lax.dynamic_slice(x, (0, pl.program_id(2) * bk),
-                               (x.shape[0], bk))
+    sx = jnp.maximum(m, ACT_SCALE_EPS) / _GRID_TOP_4    # [bm, 1]
+    k0 = pl.multiple_of(pl.program_id(2) * bk, bk)
+    xk = x_ref[:, pl.ds(k0, bk)].astype(jnp.float32)
     xq = (_snap(xk / sx, p) * sx).astype(x_ref.dtype).astype(jnp.float32)
     _accumulate(xq, wp_ref, s_ref, o_ref, p=p, bk=bk, use_scales=use_scales)
 
@@ -172,10 +174,11 @@ def _segment_call(kern, x, wp, s2d, *extra, bm, bn, bk, p, extra_specs=(),
 
 
 def _fit_segment_blocks(x, wp, p, block_m, block_n, block_k):
-    m, kp = x.shape
-    assert wp.shape[0] * (8 // p) == kp, (wp.shape, kp, p)
-    return (fit_block(m, block_m), fit_block(wp.shape[1], block_n),
-            fit_block(kp, block_k, GROUP_SIZE))
+    m, kp = x.shape[-2:]
+    assert wp.shape[-2] * (8 // p) == kp, (wp.shape, kp, p)
+    return (fit_block(m, block_m, SUBLANE), fit_block(wp.shape[-1], block_n,
+                                                      LANE),
+            fit_block(kp, block_k, LANE))
 
 
 def _prep_scales(scales, kp):
@@ -187,9 +190,9 @@ def _prep_scales(scales, kp):
 
 @functools.partial(jax.jit, static_argnames=(
     "p", "block_m", "block_n", "block_k", "act_quant", "interpret"))
-def packed_segment_matmul(x, wp, scales, *, p: int, block_m: int = 256,
-                          block_n: int = 128, block_k: int = 256,
-                          act_quant: bool = False, interpret: bool = True):
+def packed_segment_matmul(x, wp, scales, *, p: int, interpret: bool,
+                          block_m: int = 256, block_n: int = 128,
+                          block_k: int = 256, act_quant: bool = False):
     """x [M, Kp] @ unpack(wp [Kp*p//8, N]) -> [M, N] f32.
 
     scales: [Kp//16] per-group f32 or None. Pre-divide x by the activation
@@ -205,18 +208,19 @@ def packed_segment_matmul(x, wp, scales, *, p: int, block_m: int = 256,
 
 @functools.partial(jax.jit, static_argnames=(
     "p", "block_m", "block_n", "block_k", "interpret"))
-def fused_act_segment_matmul(x, sx, wp, scales, *, p: int,
+def fused_act_segment_matmul(x, sx, wp, scales, *, p: int, interpret: bool,
                              block_m: int = 256, block_n: int = 128,
-                             block_k: int = 256, interpret: bool = True):
+                             block_k: int = 256):
     """Fused-prologue segment GEMM: quantize the activations to the p-bit
     grid with per-token scales ``sx`` [M, 1] *inside* the kernel, then
     x @ unpack(wp) -> [M, N] f32.
 
     Numerically this is fake_quant(x, p, sx) followed by
-    ``packed_segment_matmul(..., act_quant=False)`` — bit-exactly, since the
-    in-kernel prologue runs the same element-wise arithmetic (divide, snap,
-    rescale, round-trip through x.dtype) — but without writing the
-    quantized activation tensor back to HBM between the two. The per-token
+    ``packed_segment_matmul(..., act_quant=False)``: the in-kernel prologue
+    runs the same element-wise arithmetic (divide, snap, rescale,
+    round-trip through x.dtype), up to the lowering of the f32 divide (a
+    few ulp of the output scale), but without writing the quantized
+    activation tensor back to HBM between the two. The per-token
     abs-max reduction itself stays in the driver: the scale spans the full
     permuted K row, which crosses segment (and therefore kernel) boundaries.
     """
@@ -233,20 +237,21 @@ def fused_act_segment_matmul(x, sx, wp, scales, *, p: int,
 
 @functools.partial(jax.jit, static_argnames=(
     "p", "block_m", "block_n", "block_k", "interpret"))
-def fused_act_selfscale_matmul(x, wp, scales, *, p: int,
+def fused_act_selfscale_matmul(x, wp, scales, *, p: int, interpret: bool,
                                block_m: int = 256, block_n: int = 128,
-                               block_k: int = 256, interpret: bool = True):
+                               block_k: int = 256):
     """Single-segment fused-prologue GEMM with the per-token abs-max scale
     computed *inside* the kernel: for a uniform-precision layer (one
     segment spans the whole K row) this removes the remaining [M, K] ->
     [M, 1] jnp reduction pass — activations are read once, scaled,
     snapped and multiplied without ever leaving VMEM.
 
-    Bit-exact with ``fused_act_segment_matmul(x, act_scale(x), ...)`` (and
-    therefore with the two-pass reference): the in-kernel reduction runs
-    the same fp32 abs-max / ``ACT_SCALE_EPS`` clamp / grid-top divide as
-    ``core.quant.abs_max_scale``, and the abs-max is row-permutation-
-    invariant so driver-side channel reordering does not perturb it.
+    Equal to ``fused_act_segment_matmul(x, act_scale(x), ...)`` (and so to
+    the two-pass reference) within a few ulp of the output scale: the
+    in-kernel reduction runs the same fp32 abs-max / ``ACT_SCALE_EPS``
+    clamp / grid-top divide as ``core.quant.abs_max_scale``, and the
+    abs-max is row-permutation-invariant so driver-side channel reordering
+    does not perturb it.
     """
     bm, bn, bk = _fit_segment_blocks(x, wp, p, block_m, block_n, block_k)
     use_scales, s2d = _prep_scales(scales, x.shape[1])
@@ -281,11 +286,10 @@ def _grouped_kernel(x_ref, wp_ref, s_ref, o_ref, *, p: int, bk: int,
 
 @functools.partial(jax.jit, static_argnames=(
     "p", "block_m", "block_n", "block_k", "act_quant", "interpret"))
-def packed_segment_matmul_grouped(x, wp, scales, *, p: int,
+def packed_segment_matmul_grouped(x, wp, scales, *, p: int, interpret: bool,
                                   block_m: int = 256, block_n: int = 128,
                                   block_k: int = 256,
-                                  act_quant: bool = False,
-                                  interpret: bool = True):
+                                  act_quant: bool = False):
     """Grouped uniform-precision segment GEMM over a stacked expert bank:
     x [E, M, Kp] @ unpack(wp [E, Kp*p//8, N]) -> [E, M, N] f32, one
     pallas_call for all E experts (the MoE serve fast path — and the
@@ -300,10 +304,8 @@ def packed_segment_matmul_grouped(x, wp, scales, *, p: int,
     e, m, kp = x.shape
     assert wp.shape[0] == e and wp.shape[1] * (8 // p) == kp, \
         (x.shape, wp.shape, p)
+    bm, bn, bk = _fit_segment_blocks(x, wp, p, block_m, block_n, block_k)
     n = wp.shape[2]
-    bm = fit_block(m, block_m)
-    bn = fit_block(n, block_n)
-    bk = fit_block(kp, block_k, GROUP_SIZE)
     use_scales = scales is not None
     if not use_scales:  # dummy operand keeps one kernel signature
         scales = jnp.ones((e, kp // GROUP_SIZE), jnp.float32)

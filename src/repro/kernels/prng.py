@@ -30,4 +30,6 @@ def hash_u32(idx, seed):
 def uniform_pm1(idx, seed):
     """Deterministic U[-1, 1) from (index, seed), float32."""
     bits = hash_u32(idx, seed) >> np.uint32(8)       # 24 mantissa-safe bits
-    return bits.astype(jnp.float32) * np.float32(2.0 / (1 << 24)) - 1.0
+    # Through int32 (exact below 2^24): Mosaic has no uint32 -> f32 cast.
+    return bits.astype(jnp.int32).astype(jnp.float32) \
+        * np.float32(2.0 / (1 << 24)) - 1.0

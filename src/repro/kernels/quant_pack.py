@@ -12,7 +12,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.qtypes import GROUP_SIZE
@@ -26,24 +25,26 @@ def _kernel(w_ref, s_ref, o_ref, *, p: int, bk: int, use_scales: bool):
     h = float(2.0 ** (1 - p))
     two_p = float(2 ** p)
     u = jnp.clip(jnp.round((w / h + (two_p - 1.0)) / 2.0), 0.0, two_p - 1.0)
-    u = u.astype(jnp.uint8)
+    # Codes are built in int32 and narrowed once at the store: Mosaic has
+    # no float32 -> uint8 cast.
+    u = u.astype(jnp.int32)
     vpb = 8 // p
     u = u.reshape(bk // vpb, vpb, w.shape[-1])
-    out = jnp.zeros((bk // vpb, w.shape[-1]), jnp.uint8)
-    for j in range(vpb):
-        out = out | (u[:, j] << np.uint8(p * j))
-    o_ref[...] = out
+    out = u[:, 0]
+    for j in range(1, vpb):
+        out = out | (u[:, j] << (p * j))
+    o_ref[...] = out.astype(jnp.uint8)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "p", "block_k", "block_n", "interpret"))
-def quantize_pack(w, scales, *, p: int, block_k: int = 256,
-                  block_n: int = 256, interpret: bool = True):
+def quantize_pack(w, scales, *, p: int, interpret: bool, block_k: int = 256,
+                  block_n: int = 256):
     """w [K, N] -> uint8 [K*p//8, N] SMOL codes (packed little-endian on K)."""
-    from .packed_matmul import fit_block
+    from .packed_matmul import LANE, fit_block
     k, n = w.shape
-    bk = fit_block(k, block_k, GROUP_SIZE)
-    bn = fit_block(n, block_n)
+    bk = fit_block(k, block_k, LANE)   # bk//16 scale rows: a multiple of 8
+    bn = fit_block(n, block_n, LANE)
     use_scales = scales is not None
     if not use_scales:
         scales = jnp.ones((k // GROUP_SIZE,), jnp.float32)

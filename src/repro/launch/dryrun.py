@@ -1,8 +1,14 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count at first init.
-# This file is the ONLY place the flag is set — smoke tests/benches see 1
-# CPU device.
+# The dry-run lowers for 512 virtual CPU devices. Both settings MUST
+# precede any jax import (jax locks platform and device count at first
+# init): pin the CPU, so a machine with an accelerator does not hand the
+# dry-run its chips, and add the device count to whatever XLA_FLAGS the
+# caller set. This file is the ONLY place the flag is set — smoke
+# tests/benches see 1 CPU device.
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"),
+    "--xla_force_host_platform_device_count=512")))
 
 import argparse          # noqa: E402
 import json              # noqa: E402
@@ -131,7 +137,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, *,
     rules = None
     t0 = time.time()
 
-    with mesh_lib.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if info["kind"] == "train":
             tcfg = specs_lib.train_config_for(arch, mesh)
             if hoist:

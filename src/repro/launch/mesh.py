@@ -8,33 +8,21 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n: int) -> dict:
-    """jax >= 0.5 takes axis_types; older releases have neither the kwarg
-    nor jax.sharding.AxisType (Auto is the implicit behaviour there)."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n} if at is not None else {}
-
-
-def set_mesh(mesh):
-    """Context manager for the ambient mesh: jax.set_mesh where available,
-    else the Mesh object itself (the pre-0.5 spelling)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips with a leading "pod"
     axis (the multi-pod dry-run proves the pod axis shards)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
-def make_mesh(shape, axes):
-    """Arbitrary mesh (tests / elastic re-mesh)."""
+def make_mesh(shape, axes, devices=None):
+    """Arbitrary mesh (tests / elastic re-mesh); ``devices`` defaults to
+    ``jax.devices()``."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def data_axes(mesh) -> tuple:

@@ -24,6 +24,7 @@ import numpy as np
 from repro import soniq
 from repro.backend import registry as backend_registry
 from repro.configs import get_config
+from repro.launch import runtime
 from repro.models import lm
 from repro.train import checkpoint as ckpt_lib
 
@@ -79,6 +80,10 @@ def main():
                          "the effective cache length)")
     args = ap.parse_args()
 
+    runtime.enable_compile_cache()
+    print(f"device: {runtime.device_summary()}")
+    if args.backend == "pallas_mosaic":
+        runtime.require_tpu("--backend pallas_mosaic")
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -12,15 +12,18 @@ degenerates gracefully to whatever devices exist. The dry-run
 from __future__ import annotations
 
 import argparse
+import os
 
 import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import soniq
+from repro.backend import registry as backend_registry
 from repro.configs import get_config
 from repro.data import synthetic
 from repro.launch import mesh as mesh_lib
+from repro.launch import runtime
 from repro.launch import sharding as sh
 from repro.models import shard as shard_ctx
 from repro.optim import adamw
@@ -52,6 +55,12 @@ def main():
     ap.add_argument("--grad-compress", action="store_true")
     args = ap.parse_args()
 
+    runtime.enable_compile_cache()
+    print(f"device: {runtime.device_summary()}")
+    # The kernel backend comes from the environment (no flag here).
+    if os.environ.get(backend_registry.ENV_VAR, "").strip() \
+            == "pallas_mosaic":
+        runtime.require_tpu(f"{backend_registry.ENV_VAR}=pallas_mosaic")
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -68,7 +77,7 @@ def main():
         vocab_size=cfg.vocab_size, seq_len=args.seq, batch_size=args.batch,
         seed=0), host_id=jax.process_index())
 
-    with mesh_lib.set_mesh(mesh), shard_ctx.sharding_rules(rules):
+    with jax.set_mesh(mesh), shard_ctx.sharding_rules(rules):
         key = jax.random.PRNGKey(0)
         state = state_lib.init_state(key, cfg, tcfg)
         state_specs = jax.eval_shape(

@@ -98,8 +98,8 @@ class EngineConfig:
     # engine construction, so it is jit-trace-stable.
     backend: Optional[str] = None
     # Allow the backend to fuse the per-decode-step activation quantization
-    # into the packed-GEMM prologue (bit-exact with the two-pass form —
-    # DESIGN.md §11). False pins the two-pass reference; benchmarks flip
+    # into the packed-GEMM prologue (equal to the two-pass form to a few
+    # ulp — DESIGN.md §11). False pins the two-pass reference; benchmarks flip
     # this to record the fused-vs-unfused delta.
     fuse_act_quant: bool = True
     # KV-cache precision (DESIGN.md §12). None = fp ring cache in

@@ -6,10 +6,10 @@ import pytest
 def _isolated_autotune_cache(tmp_path, monkeypatch):
     """Point the block-size autotune cache at a per-test tmpdir.
 
-    The suite must never read a developer's (or CI runner's)
-    ``~/.cache/soniq/autotune.json`` — a stale tuned entry would silently
-    change the block shapes every Pallas-backed test runs with — and must
-    never write there either.
+    The suite must never read the checkout's
+    ``benchmarks/autotune.json`` — a tuned entry would silently change the
+    block shapes every Pallas-backed test runs with — and must never write
+    there either.
     """
     from repro.backend import autotune
 

@@ -26,7 +26,7 @@ mesh = mesh_lib.make_mesh((4, 4), ("data", "model"))
 B, S = 8, 32
 tcfg = state_lib.TrainConfig(num_microbatches=2)
 
-with mesh_lib.set_mesh(mesh):
+with jax.set_mesh(mesh):
     state_specs = jax.eval_shape(
         lambda: state_lib.init_state(jax.random.PRNGKey(0), cfg, tcfg))
     rules = sh.activation_rules(cfg, mesh, batch=B)

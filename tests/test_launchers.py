@@ -6,14 +6,20 @@ import sys
 ENV = dict(os.environ, PYTHONPATH="src")
 
 
+def _env(tmp_path):
+    """The launchers turn on JAX's compile cache; keep it out of the
+    checkout."""
+    return dict(ENV, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"))
+
+
 def test_train_launcher_runs_and_checkpoints(tmp_path):
     ck = str(tmp_path / "ck")
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.train", "--arch",
          "h2o-danube-1.8b", "--reduced", "--steps", "6", "--batch", "2",
          "--seq", "32", "--ckpt", ck, "--hoist"],
-        env=ENV, cwd=os.getcwd(), capture_output=True, text=True,
-        timeout=900)
+        env=_env(tmp_path), cwd=os.getcwd(), capture_output=True,
+        text=True, timeout=900)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "done" in out.stdout
     assert os.path.exists(os.path.join(ck, "LATEST"))
@@ -22,8 +28,8 @@ def test_train_launcher_runs_and_checkpoints(tmp_path):
         [sys.executable, "-m", "repro.launch.train", "--arch",
          "h2o-danube-1.8b", "--reduced", "--steps", "8", "--batch", "2",
          "--seq", "32", "--ckpt", ck],
-        env=ENV, cwd=os.getcwd(), capture_output=True, text=True,
-        timeout=900)
+        env=_env(tmp_path), cwd=os.getcwd(), capture_output=True,
+        text=True, timeout=900)
     assert out2.returncode == 0, out2.stderr[-2000:]
     assert "resumed from step" in out2.stdout
 
@@ -34,8 +40,8 @@ def test_serve_launcher_generates(tmp_path):
         [sys.executable, "-m", "repro.launch.serve", "--arch",
          "mamba2-2.7b", "--reduced", "--requests", "2", "--max-batch", "2",
          "--prompt-len", "4", "--new-tokens", "6", "--cache-len", "32"],
-        env=ENV, cwd=os.getcwd(), capture_output=True, text=True,
-        timeout=900)
+        env=_env(tmp_path), cwd=os.getcwd(), capture_output=True,
+        text=True, timeout=900)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "tok/s" in out.stdout
     assert "[continuous]" in out.stdout

@@ -9,9 +9,8 @@ import traceback
 
 
 def main() -> None:
-    from . import (fig7_accuracy_bpp, fig9_layer_bpp, roofline,
-                   runtime_proxy, serve_throughput, table1_smol_variants,
-                   table2_patterns)
+    from . import (fig7_accuracy_bpp, fig9_layer_bpp, runtime_proxy,
+                   serve_throughput, table1_smol_variants, table2_patterns)
     benches = [
         ("table2_patterns", table2_patterns.main),
         # explicit empty argv: the harness's own sys.argv must not leak
@@ -20,7 +19,6 @@ def main() -> None:
         ("table1_smol_variants", table1_smol_variants.main),
         ("fig7_accuracy_bpp", fig7_accuracy_bpp.main),
         ("fig9_layer_bpp", fig9_layer_bpp.main),
-        ("roofline", roofline.main),
         # explicit empty argv: the harness's own sys.argv must not leak
         # into the benchmark's argparse
         ("serve_throughput", lambda: serve_throughput.main([])),

@@ -253,7 +253,7 @@ def main(argv=None):
             legs[label] = (time.time() - t0, eng)
         t_ring, _ = legs["ring"]
         t_paged, paged_eng = legs["paged"]
-        stats = paged_eng.paged_kv_stats()
+        stats = paged_eng.kv_stats()
         row = {
             "tok_s": round(shared_useful / t_paged, 1),
             "seconds": round(t_paged, 3),
@@ -428,14 +428,15 @@ def main(argv=None):
                       **extra)
             eng = engine_lib.DecodeEngine(fparams, fcfg,
                                           soniq.EngineConfig(**kw))
-            before = pallas_backend.grouped_matmul_call_count()
+            grouped = "packed_segment_matmul_grouped"
+            before = pallas_backend.kernel_call_counts()[grouped]
             t = fam_time(eng, enc_out)
             row = {"arch": fcfg.name, "tok_s": round(fam_useful / t, 1),
                    "seconds": round(t, 3)}
             if fam_label == "moe_packed" and eng.cfg.quant.backend_name \
                     and backend_registry.resolve(name).supports(
                         "packed_segment_matmul_grouped"):
-                assert pallas_backend.grouped_matmul_call_count() > before, \
+                assert pallas_backend.kernel_call_counts()[grouped] > before, \
                     "MoE serve did not dispatch the grouped kernel"
                 row["grouped_dispatch"] = True
             if fam_label == "ssm_chunked":

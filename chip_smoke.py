@@ -235,7 +235,7 @@ def serve_phase(cfg, backend: str):
         max_new_tokens=NEW_TOKENS, seed=i, arrival_step=step)
         for i, (n, step) in enumerate(PAGED_TAILS)]
     n3, t3, _ = _serve(paged, reqs, cfg.vocab_size)
-    stats = paged.paged_kv_stats()
+    stats = paged.kv_stats()
     if stats["prefix_hits"] < 1:
         _fail(f"the shared prefix was not reused: {stats}")
     report.update(paged_tokens=n3, paged_s=t3,

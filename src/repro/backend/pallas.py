@@ -58,40 +58,19 @@ _FALLBACKS: Counter = Counter()
 
 
 def kernel_call_counts() -> Dict[str, int]:
-    """Kernel dispatches per op, counted at trace time (not per step)."""
-    return dict(_KERNEL_CALLS)
+    """How many times each kernel op was traced into a program, by op
+    name (a ``Counter``: an op never traced reads 0).
+
+    It counts traces, not calls: the count ticks when a jitted function
+    that uses the kernel is traced, and a compiled step that runs the
+    kernel a thousand times adds nothing. ``fused_act_segment_matmul``
+    counts both prologue forms of the fused activation-quant GEMM."""
+    return Counter(_KERNEL_CALLS)
 
 
 def fallback_counts() -> Dict[str, int]:
     """Reference-math fallbacks per op, counted at trace time."""
     return dict(_FALLBACKS)
-
-
-def fused_act_call_count() -> int:
-    """Dispatches of the fused activation-quant GEMM kernel (either
-    prologue form)."""
-    return _KERNEL_CALLS["fused_act_segment_matmul"]
-
-
-def grouped_matmul_call_count() -> int:
-    """Dispatches of the grouped (stacked-expert) segment-GEMM kernel."""
-    return _KERNEL_CALLS["packed_segment_matmul_grouped"]
-
-
-def fake_quant_kernel_call_count() -> int:
-    """Dispatches of the fused fake_quant forward kernel."""
-    return _KERNEL_CALLS["fake_quant"]
-
-
-def qkv_attn_call_count() -> int:
-    """Dispatches of the ring quantized-KV flash-decode kernel
-    (DESIGN.md §12)."""
-    return _KERNEL_CALLS["qkv_attn_decode"]
-
-
-def qkv_attn_paged_call_count() -> int:
-    """Dispatches of the paged flash-decode kernel (DESIGN.md §13)."""
-    return _KERNEL_CALLS["qkv_attn_decode_paged"]
 
 
 class PallasBackend(Backend):

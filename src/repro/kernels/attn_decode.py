@@ -153,6 +153,7 @@ def qkv_attn_decode(q, k_codes, v_codes, k_scale, v_scale, kv_pos, q_pos,
     bh = lambda i, j: (i, j, 0, 0, 0)
     out = pl.pallas_call(
         functools.partial(_ring_kernel, window=window),
+        name="qkv_attn_decode",
         grid=(b, hk),
         in_specs=[
             pl.BlockSpec((1, 1, sg, d), lambda i, j: (i, j, 0, 0)),
@@ -202,6 +203,7 @@ def qkv_attn_decode_paged(q, k_codes, v_codes, k_scale, v_scale, pool_pos,
     h_only = lambda i, j, tbl: (j, 0, 0, 0)
     out = pl.pallas_call(
         functools.partial(_paged_kernel, window=window),
+        name="qkv_attn_decode_paged",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, hk),

@@ -24,7 +24,7 @@ from jax.experimental import pallas as pl
 from repro.core.qtypes import GROUP_SIZE
 
 
-def _kernel(x_ref, pb_ref, s_ref, o_ref):
+def _fake_quant_kernel(x_ref, pb_ref, s_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)
     p = pb_ref[...]                       # [1, bk] per-channel precision
     s = s_ref[...]                        # [bm, 1] per row or [1, bk]
@@ -64,7 +64,8 @@ def fake_quant(x, pbits, scale, *, row_scale: bool, interpret: bool,
     else:
         s_op, s_spec = per_channel(scale), k_spec
     return pl.pallas_call(
-        _kernel,
+        _fake_quant_kernel,
+        name="fake_quant",
         grid=(m // bm, k // bk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j: (i, j)),

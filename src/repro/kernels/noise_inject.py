@@ -19,7 +19,8 @@ from repro.core.qtypes import GROUP_SIZE
 from . import prng
 
 
-def _kernel(seed_ref, w_ref, s_ref, o_ref, *, bk: int, bn: int, n_total: int):
+def _noise_inject_kernel(seed_ref, w_ref, s_ref, o_ref, *, bk: int,
+                         bn: int, n_total: int):
     i, j = pl.program_id(0), pl.program_id(1)
     w = w_ref[...].astype(jnp.float32)
     sig = jax.nn.sigmoid(s_ref[...].astype(jnp.float32))      # [bk//16, 1]
@@ -46,9 +47,10 @@ def noise_inject(w, s, seed, *, interpret: bool, block_k: int = 256,
     bn = fit_block(n, block_n, LANE)
     s2d = jnp.asarray(s, jnp.float32).reshape(-1, 1)
     seed_arr = jnp.asarray([seed], jnp.uint32)
-    kern = functools.partial(_kernel, bk=bk, bn=bn, n_total=n)
+    kern = functools.partial(_noise_inject_kernel, bk=bk, bn=bn, n_total=n)
     return pl.pallas_call(
         kern,
+        name="noise_inject",
         grid=(k // bk, n // bn),
         in_specs=[
             pl.BlockSpec((1,), lambda i, j: (0,)),   # seed (SMEM-sized)

@@ -102,8 +102,8 @@ def _accumulate(xq, wp_ref, s_ref, o_ref, *, p: int, bk: int,
     o_ref[...] += jax.lax.dot(xq, wd, preferred_element_type=jnp.float32)
 
 
-def _kernel(x_ref, wp_ref, s_ref, o_ref, *, p: int, bk: int,
-            act_quant: bool, use_scales: bool):
+def _segment_kernel(x_ref, wp_ref, s_ref, o_ref, *, p: int, bk: int,
+                    act_quant: bool, use_scales: bool):
     x = x_ref[...].astype(jnp.float32)
     if act_quant:
         x = _snap(x, p)
@@ -148,17 +148,19 @@ def _fused_selfscale_kernel(x_ref, wp_ref, s_ref, o_ref, *, p: int,
     _accumulate(xq, wp_ref, s_ref, o_ref, p=p, bk=bk, use_scales=use_scales)
 
 
-def _segment_call(kern, x, wp, s2d, *extra, bm, bn, bk, p, extra_specs=(),
-                  interpret, x_spec=None):
+def _segment_call(name, kern, x, wp, s2d, *extra, bm, bn, bk, p,
+                  extra_specs=(), interpret, x_spec=None):
     """Shared pallas_call assembly of the segment GEMMs: (M/bm, N/bn,
     Kp/bk) grid with K innermost, x/wp/per-group-scale block specs (any
     ``extra`` operands slot between x and wp), f32 output. ``x_spec``
     overrides the default K-tiled x block (the self-scale kernel pins the
-    full K row instead)."""
+    full K row instead). ``name`` is the kernel's stable name: the HLO
+    custom call, and so the profiler's op, carries it."""
     m, kp = x.shape
     n = wp.shape[1]
     return pl.pallas_call(
         kern,
+        name=name,
         grid=(m // bm, n // bn, kp // bk),
         in_specs=[
             x_spec or pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
@@ -200,10 +202,10 @@ def packed_segment_matmul(x, wp, scales, *, p: int, interpret: bool,
     """
     bm, bn, bk = _fit_segment_blocks(x, wp, p, block_m, block_n, block_k)
     use_scales, s2d = _prep_scales(scales, x.shape[1])
-    kern = functools.partial(_kernel, p=p, bk=bk, act_quant=act_quant,
-                             use_scales=use_scales)
-    return _segment_call(kern, x, wp, s2d, bm=bm, bn=bn, bk=bk, p=p,
-                         interpret=interpret)
+    kern = functools.partial(_segment_kernel, p=p, bk=bk,
+                             act_quant=act_quant, use_scales=use_scales)
+    return _segment_call("packed_segment_matmul", kern, x, wp, s2d, bm=bm,
+                         bn=bn, bk=bk, p=p, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -230,7 +232,8 @@ def fused_act_segment_matmul(x, sx, wp, scales, *, p: int, interpret: bool,
     kern = functools.partial(_fused_kernel, p=p, bk=bk,
                              use_scales=use_scales)
     sx_spec = pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0))
-    return _segment_call(kern, x, wp, s2d, jnp.asarray(sx, jnp.float32),
+    return _segment_call("fused_act_segment_matmul", kern, x, wp, s2d,
+                         jnp.asarray(sx, jnp.float32),
                          bm=bm, bn=bn, bk=bk, p=p, extra_specs=(sx_spec,),
                          interpret=interpret)
 
@@ -258,8 +261,9 @@ def fused_act_selfscale_matmul(x, wp, scales, *, p: int, interpret: bool,
     kern = functools.partial(_fused_selfscale_kernel, p=p, bk=bk,
                              use_scales=use_scales)
     x_spec = pl.BlockSpec((bm, x.shape[1]), lambda i, j, k: (i, 0))
-    return _segment_call(kern, x, wp, s2d, bm=bm, bn=bn, bk=bk, p=p,
-                         interpret=interpret, x_spec=x_spec)
+    return _segment_call("fused_act_selfscale_matmul", kern, x, wp, s2d,
+                         bm=bm, bn=bn, bk=bk, p=p, interpret=interpret,
+                         x_spec=x_spec)
 
 
 def _grouped_kernel(x_ref, wp_ref, s_ref, o_ref, *, p: int, bk: int,
@@ -314,6 +318,7 @@ def packed_segment_matmul_grouped(x, wp, scales, *, p: int, interpret: bool,
                              act_quant=act_quant, use_scales=use_scales)
     return pl.pallas_call(
         kern,
+        name="packed_segment_matmul_grouped",
         grid=(e, m // bm, n // bn, kp // bk),
         in_specs=[
             pl.BlockSpec((1, bm, bk), lambda ei, i, j, k: (ei, i, k)),

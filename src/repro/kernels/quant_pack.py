@@ -17,7 +17,8 @@ from jax.experimental import pallas as pl
 from repro.core.qtypes import GROUP_SIZE
 
 
-def _kernel(w_ref, s_ref, o_ref, *, p: int, bk: int, use_scales: bool):
+def _quantize_pack_kernel(w_ref, s_ref, o_ref, *, p: int, bk: int,
+                          use_scales: bool):
     w = w_ref[...].astype(jnp.float32)
     if use_scales:
         sig = jnp.repeat(s_ref[...].astype(jnp.float32), GROUP_SIZE, axis=0)
@@ -49,9 +50,11 @@ def quantize_pack(w, scales, *, p: int, interpret: bool, block_k: int = 256,
     if not use_scales:
         scales = jnp.ones((k // GROUP_SIZE,), jnp.float32)
     s2d = scales.reshape(-1, 1).astype(jnp.float32)
-    kern = functools.partial(_kernel, p=p, bk=bk, use_scales=use_scales)
+    kern = functools.partial(_quantize_pack_kernel, p=p, bk=bk,
+                             use_scales=use_scales)
     return pl.pallas_call(
         kern,
+        name="quantize_pack",
         grid=(k // bk, n // bn),
         in_specs=[
             pl.BlockSpec((bk, bn), lambda i, j: (i, j)),

@@ -144,7 +144,7 @@ def main():
           f"({total_new / dt:.1f} tok/s, {eng.sched.step_count} engine "
           f"steps, max_batch {args.max_batch})")
     if args.kv_layout == "paged":
-        st = eng.paged_kv_stats()
+        st = eng.kv_stats()
         print(f"[paged-kv] {st['num_pages']} pages x {st['page_size']} "
               f"tokens, peak resident {st['peak_resident_pages']} pages "
               f"({st['peak_resident_payload_bytes']:,} payload bytes of "

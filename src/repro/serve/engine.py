@@ -36,7 +36,7 @@ from repro.core.phases import Phase
 from repro.core.qtypes import QuantConfig
 from repro.models import lm
 
-from . import kv_pool
+from . import kv_pool, kv_quant, spans
 from .scheduler import DECODE, Completion, Request, Scheduler
 
 
@@ -335,6 +335,9 @@ class DecodeEngine(_PackedEngine):
         # it and recommit the accepted prefix (see _spec_step).
         self._seq_state = self.caps.state != "kv"
         b = ecfg.max_batch
+        # Ring length per slot: cache_len clipped to the window.
+        self._clen = min(ecfg.cache_len, self.cfg.window) \
+            if self.cfg.window else ecfg.cache_len
 
         # Self-speculative decoding (DESIGN.md §14): a draft step running
         # the low-slice forward (same packed weights, high-bit carriers
@@ -431,11 +434,32 @@ class DecodeEngine(_PackedEngine):
         else:
             self.pool = None
             self.sched = Scheduler(b)
-        # Speculation telemetry (benchmarks record the mean accepted
-        # draft length next to tokens/s).
-        self.spec_rounds = 0
-        self.spec_drafted = 0
-        self.spec_accepted = 0
+        # Per-step records and speculation totals (serve/spans.py).
+        self.stats = spans.EngineStats()
+
+    def init_cache(self, batch: int):
+        """The device cache, and from its shapes the KV room in positions
+        and the payload bytes of one position (``kv_stats``)."""
+        cache = super().init_cache(batch)
+        if self.pool is not None:
+            room = self.pool.capacity * self.pool.page_size
+            per_pos = kv_pool.paged_payload_bytes_per_page(cache) \
+                // self.pool.page_size
+        else:
+            room = batch * self._clen
+            per_pos = kv_quant.cache_payload_bytes(cache) // room
+        self._kv_room = room if per_pos else 0     # 0: no K/V to hold
+        self._kv_position_bytes = per_pos
+        return cache
+
+    def _live_kv(self, widths: Optional[Dict[int, int]] = None) -> int:
+        """KV positions the active slots hold once ``widths`` more tokens
+        are fed: ``min(n_fed, ring length)`` per slot, from host state."""
+        if not self._kv_room:
+            return 0
+        w = widths or {}
+        return sum(min(st.n_fed + w.get(s, 0), self._clen)
+                   for s, st in self.sched.slots.items())
 
     # --------------------------------------------------------- requests ----
     def submit(self, request: Request) -> int:
@@ -527,7 +551,23 @@ class DecodeEngine(_PackedEngine):
         draft-k/verify-1 round instead (DESIGN.md §14) — same admission,
         same completions contract, 1..k+1 tokens committed per decoding
         slot per step.
+
+        Every phase runs under an ``engine.*`` span and the step leaves a
+        record in ``self.stats`` (serve/spans.py).
         """
+        with self.stats.step() as rec:
+            with self.stats.phase("admit"):
+                admitted = self._admit()
+            rec.admitted = tuple(req.request_id for _, req in admitted)
+            rec.reserved_kv = self._kv_room
+            done = self._spec_step(rec) if self.ecfg.spec_tokens > 0 \
+                else self._plain_step(rec)
+            rec.finished = tuple(c.request_id for c in done)
+        return done
+
+    def _admit(self) -> List[Tuple[int, Request]]:
+        """Admit arrived requests: one slot-reset program for the whole
+        set, the per-slot sampling keys and, paged, the pool admission."""
         b = self.ecfg.max_batch
         if self.cache is None:
             self.cache = self.init_cache(b)
@@ -548,63 +588,82 @@ class DecodeEngine(_PackedEngine):
                         # pages — prefill starts after them.
                         self.sched.slots[slot].n_fed = shared
                         self._table_dirty = True
-        if self.ecfg.spec_tokens > 0:
-            return self._spec_step()
-        plan = self.sched.plan(self.chunk)
+        return admitted
+
+    def _plain_step(self, rec: spans.StepRecord) -> List[Completion]:
+        """The prefill-width or decode program over every active slot."""
+        b = self.ecfg.max_batch
+        phase = self.stats.phase
+        with phase("plan"):
+            plan = self.sched.plan(self.chunk)
+            widths = {s: len(t) for s, t in plan.items()}
+            if plan and self.pool is not None:
+                ops = kv_pool.StepOps()
+                for slot, n in widths.items():
+                    self.pool.prepare(slot, self.sched.slots[slot].n_fed, n,
+                                      ops)
+                if ops.any():
+                    self._table_dirty = True
+                self._flush_pool_ops(ops)
         if not plan:                       # idle: let queued arrivals age in
-            return self.sched.advance({}, {})
-        widths = {s: len(t) for s, t in plan.items()}
-        if self.pool is not None:
-            ops = kv_pool.StepOps()
-            for slot, n in widths.items():
-                self.pool.prepare(slot, self.sched.slots[slot].n_fed, n,
-                                  ops)
-            if ops.any():
-                self._table_dirty = True
-            self._flush_pool_ops(ops)
-        counts = np.zeros((b,), np.int32)
-        for slot in plan:
-            counts[slot] = len(self.sched.slots[slot].generated)
-        if max(widths.values()) > 1:
-            c = self.chunk                 # fixed width: one compiled shape
-            tokens = np.zeros((b, c), np.int32)
-            pos = np.full((b, c), -1, np.int32)
-            last = np.zeros((b,), np.int32)
-            for slot, toks in plan.items():
-                n = widths[slot]
-                st = self.sched.slots[slot]
-                tokens[slot, :n] = toks
-                pos[slot, :n] = st.n_fed + np.arange(n)
-                last[slot] = n - 1
-            out, self.cache = self._prefill(self.params, self.cache,
-                                            tokens, pos, last, self._keys,
-                                            self._temps, counts)
-        else:
-            tokens = np.zeros((b,), np.int32)
-            pos = np.zeros((b,), np.int32)
-            active = np.zeros((b,), bool)
-            for slot, toks in plan.items():
-                tokens[slot] = toks[0]
-                pos[slot] = self.sched.slots[slot].n_fed
-                active[slot] = True
-            out, self.cache = self._decode(self.params, self.cache,
-                                           tokens, pos, active, self._keys,
-                                           self._temps, counts)
-        # soniq-lint: disable=SQ005(the one budgeted [B]-int sync per step)
-        sampled = np.asarray(out)
-        slot_of = {st.request.request_id: s
-                   for s, st in self.sched.slots.items()}
-        # Post-step fed counts, captured before advance() pops finished
-        # slots: note_filled's wrapped-through guard needs the TRUE fed
-        # count (prompt + generated - 1), not the prompt length — a
-        # wrap-overwritten page must never register as prompt content.
-        fed_of = {st.request.request_id: st.n_fed + widths.get(s, 0)
-                  for s, st in self.sched.slots.items()}
-        done = self.sched.advance(
-            widths, {s: int(sampled[s]) for s in plan})
-        if self.pool is not None:
-            self._paged_after_advance(done, slot_of, fed_of, plan,
-                                      kv_pool.StepOps())
+            with phase("advance"):
+                return self.sched.advance({}, {})
+        prefill = max(widths.values()) > 1
+        with phase("inputs"):
+            rec.kind = "prefill" if prefill else "decode"
+            rec.fed = sum(widths.values())
+            rec.rows = b * (self.chunk if prefill else 1)
+            rec.live_kv = self._live_kv(widths)
+            counts = np.zeros((b,), np.int32)
+            for slot in plan:
+                counts[slot] = len(self.sched.slots[slot].generated)
+            if prefill:
+                c = self.chunk             # fixed width: one compiled shape
+                tokens = np.zeros((b, c), np.int32)
+                pos = np.full((b, c), -1, np.int32)
+                last = np.zeros((b,), np.int32)
+                for slot, toks in plan.items():
+                    n = widths[slot]
+                    st = self.sched.slots[slot]
+                    tokens[slot, :n] = toks
+                    pos[slot, :n] = st.n_fed + np.arange(n)
+                    last[slot] = n - 1
+            else:
+                tokens = np.zeros((b,), np.int32)
+                pos = np.zeros((b,), np.int32)
+                active = np.zeros((b,), bool)
+                for slot, toks in plan.items():
+                    tokens[slot] = toks[0]
+                    pos[slot] = self.sched.slots[slot].n_fed
+                    active[slot] = True
+        with phase("dispatch"):
+            if prefill:
+                out, self.cache = self._prefill(self.params, self.cache,
+                                                tokens, pos, last,
+                                                self._keys, self._temps,
+                                                counts)
+            else:
+                out, self.cache = self._decode(self.params, self.cache,
+                                               tokens, pos, active,
+                                               self._keys, self._temps,
+                                               counts)
+        with phase("sync"):
+            # soniq-lint: disable=SQ005(the one budgeted [B]-int sync per step)
+            sampled = np.asarray(out)
+        with phase("advance"):
+            slot_of = {st.request.request_id: s
+                       for s, st in self.sched.slots.items()}
+            # Post-step fed counts, captured before advance() pops finished
+            # slots: note_filled's wrapped-through guard needs the TRUE fed
+            # count (prompt + generated - 1), not the prompt length — a
+            # wrap-overwritten page must never register as prompt content.
+            fed_of = {st.request.request_id: st.n_fed + widths.get(s, 0)
+                      for s, st in self.sched.slots.items()}
+            done = self.sched.advance(
+                widths, {s: int(sampled[s]) for s in plan})
+            if self.pool is not None:
+                self._paged_after_advance(done, slot_of, fed_of, plan,
+                                          kv_pool.StepOps())
         return done
 
     def _paged_after_advance(self, done, slot_of, fed_of, plan, ops):
@@ -675,7 +734,7 @@ class DecodeEngine(_PackedEngine):
         committed.append(int(rng.choice(len(q), p=q)))
         return committed
 
-    def _spec_step(self) -> List[Completion]:
+    def _spec_step(self, rec: spans.StepRecord) -> List[Completion]:
         """One speculative engine round (DESIGN.md §14): draft k tokens
         per decoding slot with the low-slice forward, verify them in ONE
         batched full-mix ``lm.verify_step`` of fixed width k+1 (which
@@ -693,25 +752,27 @@ class DecodeEngine(_PackedEngine):
         b = self.ecfg.max_batch
         k = self.ecfg.spec_tokens
         c = self.spec_width                             # k + 1
-        plan = self.sched.plan(c)
+        phase = self.stats.phase
+        with phase("plan"):
+            plan = self.sched.plan(c)
+            base_fed = {s: self.sched.slots[s].n_fed for s in plan}
+            decode_slots = [s for s in plan
+                            if self.sched.slots[s].phase == DECODE]
+            draft_slots = [s for s in decode_slots
+                           if base_fed[s] + c <= self._clen]
+            if plan and self.pool is not None:
+                ops = kv_pool.StepOps()
+                for s in plan:
+                    w = c if s in draft_slots else \
+                        (1 if s in decode_slots else len(plan[s]))
+                    self.pool.prepare(s, base_fed[s], w, ops)
+                if ops.any():
+                    self._table_dirty = True
+                self._flush_pool_ops(ops)
         if not plan:                       # idle: let queued arrivals age in
-            return self.sched.advance({}, {})
-        clen = min(self.ecfg.cache_len, self.cfg.window) \
-            if self.cfg.window else self.ecfg.cache_len
-        base_fed = {s: self.sched.slots[s].n_fed for s in plan}
-        decode_slots = [s for s in plan
-                        if self.sched.slots[s].phase == DECODE]
-        draft_slots = [s for s in decode_slots if base_fed[s] + c <= clen]
-
-        if self.pool is not None:
-            ops = kv_pool.StepOps()
-            for s in plan:
-                w = c if s in draft_slots else \
-                    (1 if s in decode_slots else len(plan[s]))
-                self.pool.prepare(s, base_fed[s], w, ops)
-            if ops.any():
-                self._table_dirty = True
-            self._flush_pool_ops(ops)
+            with phase("advance"):
+                return self.sched.advance({}, {})
+        rec.kind = "spec"
 
         # Recurrent-state families: snapshot the sequential (h/conv)
         # leaves before any draft forward touches them — the SSM decode
@@ -721,34 +782,41 @@ class DecodeEngine(_PackedEngine):
         # the exact state via the chunked SSD scan (DESIGN.md §17).
         snap = None
         if self._seq_state and draft_slots:
-            snap = self._snap(self.cache)
+            with phase("dispatch"):
+                snap = self._snap(self.cache)
 
         # --- draft sub-steps: low-slice forward, decode-phase slots only
         # (a draft write to a PROMPT position would never be rewritten by
         # verify, so prefill-phase slots sit out with pos = -1).
-        cur = np.zeros((b,), np.int32)
-        for s in decode_slots:
-            cur[s] = int(plan[s][0])
-        hot = [s for s in decode_slots
-               if self.sched.slots[s].request.temperature > 0]
-        round_rng = {s: self._spec_rng(self.sched.slots[s], 0x5EC)
-                     for s in hot}
-        drafts = {s: [] for s in draft_slots}
-        dprobs = {s: [] for s in draft_slots}
-        active = np.zeros((b,), bool)
-        for s in draft_slots:
-            active[s] = True
-        if draft_slots:
-            for j in range(k):
+        with phase("inputs"):
+            cur = np.zeros((b,), np.int32)
+            for s in decode_slots:
+                cur[s] = int(plan[s][0])
+            hot = [s for s in decode_slots
+                   if self.sched.slots[s].request.temperature > 0]
+            round_rng = {s: self._spec_rng(self.sched.slots[s], 0x5EC)
+                         for s in hot}
+            drafts = {s: [] for s in draft_slots}
+            dprobs = {s: [] for s in draft_slots}
+            active = np.zeros((b,), bool)
+            for s in draft_slots:
+                active[s] = True
+        for j in range(k if draft_slots else 0):
+            with phase("inputs"):
                 pos = np.zeros((b,), np.int32)
                 for s in draft_slots:
                     pos[s] = base_fed[s] + j
+                rec.fed += len(draft_slots)
+                rec.rows += b
+            with phase("dispatch"):
                 gr, lg, self.cache = self._draft(self.params, self.cache,
                                                  cur, pos, active)
+            with phase("sync"):
                 # soniq-lint: disable=SQ005(host acceptance needs the draft)
                 gr = np.asarray(gr)
                 # soniq-lint: disable=SQ005(logits only cross when sampling)
                 lgh = np.asarray(lg, np.float32) if hot else None
+            with phase("inputs"):
                 for s in draft_slots:
                     if self.sched.slots[s].request.temperature > 0:
                         p = _softmax(
@@ -767,53 +835,63 @@ class DecodeEngine(_PackedEngine):
             # chunked SSD scan threads h0 through the fed lanes), so its
             # per-lane logits are exactly the token-by-token full-mix
             # stream.
-            self.cache = self._restore(self.cache, snap)
-        tokens = np.zeros((b, c), np.int32)
-        pos = np.full((b, c), -1, np.int32)
-        for s, toks in plan.items():
-            feed = [int(plan[s][0])] + drafts[s] if s in draft_slots \
-                else [int(x) for x in toks[:c]]
-            tokens[s, :len(feed)] = feed
-            pos[s, :len(feed)] = base_fed[s] + np.arange(len(feed))
-        gr, lg, self.cache = self._verify(self.params, self.cache,
-                                          tokens, pos)
-        # soniq-lint: disable=SQ005(per-round acceptance sync, DESIGN §14)
-        gr = np.asarray(gr)                             # [B, C] argmaxes
-        need_lg = bool(hot) or any(
-            self.sched.slots[s].request.temperature > 0 for s in plan
-            if s not in decode_slots)
-        # soniq-lint: disable=SQ005(logits only cross when a slot samples)
-        lgh = np.asarray(lg, np.float32) if need_lg else None   # [B, C, V]
+            with phase("dispatch"):
+                self.cache = self._restore(self.cache, snap)
+        with phase("inputs"):
+            tokens = np.zeros((b, c), np.int32)
+            pos = np.full((b, c), -1, np.int32)
+            lanes = {}
+            for s, toks in plan.items():
+                feed = [int(plan[s][0])] + drafts[s] if s in draft_slots \
+                    else [int(x) for x in toks[:c]]
+                tokens[s, :len(feed)] = feed
+                pos[s, :len(feed)] = base_fed[s] + np.arange(len(feed))
+                lanes[s] = len(feed)
+            rec.fed += sum(lanes.values())
+            rec.rows += b * c
+            rec.live_kv = self._live_kv(lanes)
+        with phase("dispatch"):
+            gr, lg, self.cache = self._verify(self.params, self.cache,
+                                              tokens, pos)
+        with phase("sync"):
+            # soniq-lint: disable=SQ005(per-round acceptance sync, DESIGN §14)
+            gr = np.asarray(gr)                         # [B, C] argmaxes
+            need_lg = bool(hot) or any(
+                self.sched.slots[s].request.temperature > 0 for s in plan
+                if s not in decode_slots)
+            # soniq-lint: disable=SQ005(logits only cross when a slot samples)
+            lgh = np.asarray(lg, np.float32) if need_lg else None  # [B,C,V]
 
         # --- host-side acceptance + commit
-        fed = {}
-        sampled = {}
-        for s, toks in plan.items():
-            st = self.sched.slots[s]
-            if s not in decode_slots:
-                n = len(toks)
-                fed[s] = n
-                if st.n_fed + n >= len(st.request.prompt):
-                    # Prompt completes this step: its last lane's logits
-                    # seed sampling (argmax at temp 0 — identical to the
-                    # device sampler's greedy branch).
-                    sampled[s] = int(gr[s, n - 1]) \
-                        if st.request.temperature <= 0 \
-                        else self._pick(st, lgh[s, n - 1])
-                continue
-            committed = self._accept(st, drafts.get(s, []),
-                                     dprobs.get(s, []), gr[s],
-                                     None if lgh is None else lgh[s],
-                                     round_rng.get(s))
-            a = len(committed) - 1          # accepted drafts
-            fed[s] = 1 + a
-            sampled[s] = committed
-            if s in draft_slots:
-                self.spec_rounds += 1
-                self.spec_drafted += k
-                self.spec_accepted += a
-                if self.pool is not None and a < k:
-                    self._table_dirty = True
+        with phase("advance"):
+            fed = {}
+            sampled = {}
+            for s, toks in plan.items():
+                st = self.sched.slots[s]
+                if s not in decode_slots:
+                    n = len(toks)
+                    fed[s] = n
+                    if st.n_fed + n >= len(st.request.prompt):
+                        # Prompt completes this step: its last lane's
+                        # logits seed sampling (argmax at temp 0 —
+                        # identical to the device sampler's greedy branch).
+                        sampled[s] = int(gr[s, n - 1]) \
+                            if st.request.temperature <= 0 \
+                            else self._pick(st, lgh[s, n - 1])
+                    continue
+                committed = self._accept(st, drafts.get(s, []),
+                                         dprobs.get(s, []), gr[s],
+                                         None if lgh is None else lgh[s],
+                                         round_rng.get(s))
+                a = len(committed) - 1          # accepted drafts
+                fed[s] = 1 + a
+                sampled[s] = committed
+                if s in draft_slots:
+                    self.stats.spec_rounds += 1
+                    self.stats.spec_drafted += k
+                    self.stats.spec_accepted += a
+                    if self.pool is not None and a < k:
+                        self._table_dirty = True
 
         # --- recurrent-state recommit: verify advanced the sequential
         # state through ALL c fed lanes, accepted or not, and rejected
@@ -827,26 +905,31 @@ class DecodeEngine(_PackedEngine):
         # state is bitwise the verify result), and ring K/V rewrites of
         # the same (position, token) are idempotent.
         if snap is not None:
-            self.cache = self._restore(self.cache, snap)
-            pos2 = pos.copy()
-            for s in draft_slots:
-                pos2[s, fed[s]:] = -1
-            _, _, self.cache = self._verify(self.params, self.cache,
-                                            tokens, pos2)
+            with phase("inputs"):
+                pos2 = pos.copy()
+                for s in draft_slots:
+                    pos2[s, fed[s]:] = -1
+                rec.fed += int((pos2 >= 0).sum())
+                rec.rows += b * c
+            with phase("dispatch"):
+                self.cache = self._restore(self.cache, snap)
+                _, _, self.cache = self._verify(self.params, self.cache,
+                                                tokens, pos2)
 
         # --- paged rollback of wholly-rejected pages, then advance
-        slot_of = {st.request.request_id: s
-                   for s, st in self.sched.slots.items()}
-        fed_of = {st.request.request_id: st.n_fed + fed.get(s, 0)
-                  for s, st in self.sched.slots.items()}
-        ops = kv_pool.StepOps()
-        if self.pool is not None:
-            for s in draft_slots:
-                self.pool.rollback(s, base_fed[s] + fed[s],
-                                   base_fed[s] + c, ops)
-        done = self.sched.advance(fed, sampled)
-        if self.pool is not None:
-            self._paged_after_advance(done, slot_of, fed_of, plan, ops)
+        with phase("advance"):
+            slot_of = {st.request.request_id: s
+                       for s, st in self.sched.slots.items()}
+            fed_of = {st.request.request_id: st.n_fed + fed.get(s, 0)
+                      for s, st in self.sched.slots.items()}
+            ops = kv_pool.StepOps()
+            if self.pool is not None:
+                for s in draft_slots:
+                    self.pool.rollback(s, base_fed[s] + fed[s],
+                                       base_fed[s] + c, ops)
+            done = self.sched.advance(fed, sampled)
+            if self.pool is not None:
+                self._paged_after_advance(done, slot_of, fed_of, plan, ops)
         return done
 
     def _pick(self, st, lg_row) -> int:
@@ -863,13 +946,14 @@ class DecodeEngine(_PackedEngine):
         accepted, and the mean accepted draft length per round (the
         benchmark's acceptance figure — k accepted means every draft
         survived verification)."""
+        st = self.stats
         return {
             "spec_tokens": self.ecfg.spec_tokens,
-            "rounds": self.spec_rounds,
-            "drafted": self.spec_drafted,
-            "accepted": self.spec_accepted,
-            "mean_accepted": (self.spec_accepted / self.spec_rounds
-                              if self.spec_rounds else 0.0),
+            "rounds": st.spec_rounds,
+            "drafted": st.spec_drafted,
+            "accepted": st.spec_accepted,
+            "mean_accepted": (st.spec_accepted / st.spec_rounds
+                              if st.spec_rounds else 0.0),
         }
 
     # ----------------------------------------------------- cancellation ----
@@ -905,29 +989,44 @@ class DecodeEngine(_PackedEngine):
         return self.sched.evict(slot)
 
     # ---------------------------------------------------------- metrics ----
-    def paged_kv_stats(self) -> dict:
-        """Occupancy / sharing metrics of the paged KV pool (benchmarks
-        record these next to tokens/s — DESIGN.md §13). Byte figures count
-        K/V *payload* only (codes + scales / fp k and v), matching
-        ``kv_quant.cache_payload_bytes`` on the ring side; a "page" spans
-        every layer (the allocator maps one physical id in all layers)."""
-        assert self.pool is not None, "paged_kv_stats needs kv_layout='paged'"
+    def kv_stats(self) -> dict:
+        """KV payload bytes reserved against bytes in use, for either
+        layout (benchmarks record these next to tokens/s — DESIGN.md §13).
+        Byte figures count K/V *payload* only (codes + scales / fp k and
+        v), as ``kv_quant.cache_payload_bytes`` does; the bytes of one
+        position come from the cache's shapes, taken once when it was
+        made. In use: ``min(n_fed, ring length)`` positions per active
+        slot. Reserved: every slot's ring, or, paged, every allocatable
+        page. The paged layout adds its occupancy and sharing figures; a
+        "page" spans every layer (the allocator maps one physical id in
+        all layers)."""
         assert self.cache is not None, "run at least one step first"
-        per_page = kv_pool.paged_payload_bytes_per_page(self.cache)
-        pool = self.pool
-        return {
-            "page_size": pool.page_size,
-            "num_pages": pool.num_pages,
-            "page_payload_bytes": per_page,
-            "resident_pages": pool.resident_pages,
-            "peak_resident_pages": pool.peak_resident,
-            "resident_payload_bytes": pool.resident_pages * per_page,
-            "peak_resident_payload_bytes": pool.peak_resident * per_page,
-            "reserved_payload_bytes": pool.capacity * per_page,
-            "prefix_hits": pool.hits,
-            "prefix_lookups": pool.lookups,
-            "prefix_hit_rate": pool.prefix_hit_rate,
+        per_pos = self._kv_position_bytes
+        live = self._live_kv()
+        stats = {
+            "kv_layout": self.ecfg.kv_layout,
+            "position_payload_bytes": per_pos,
+            "reserved_positions": self._kv_room,
+            "live_positions": live,
+            "reserved_payload_bytes": self._kv_room * per_pos,
+            "live_payload_bytes": live * per_pos,
         }
+        pool = self.pool
+        if pool is not None:
+            per_page = per_pos * pool.page_size
+            stats.update({
+                "page_size": pool.page_size,
+                "num_pages": pool.num_pages,
+                "page_payload_bytes": per_page,
+                "resident_pages": pool.resident_pages,
+                "peak_resident_pages": pool.peak_resident,
+                "resident_payload_bytes": pool.resident_pages * per_page,
+                "peak_resident_payload_bytes": pool.peak_resident * per_page,
+                "prefix_hits": pool.hits,
+                "prefix_lookups": pool.lookups,
+                "prefix_hit_rate": pool.prefix_hit_rate,
+            })
+        return stats
 
     # -------------------------------------------------------- streaming ----
     def run(self) -> Iterator[Completion]:

@@ -295,16 +295,17 @@ def test_pallas_driver_engages_fused_prologue():
     from repro.backend import pallas as pallas_mod
     sp, qcfg = _serve_leaf()
     x = jax.random.normal(jax.random.PRNGKey(2), (4, 256))
-    before = pallas_mod.fused_act_call_count()
+    before = pallas_mod.kernel_call_counts()["fused_act_segment_matmul"]
     smol.linear_apply(sp, x, QuantConfig(mode="serve", mix=qcfg.mix,
                                          backend="pallas_interpret"))
-    assert pallas_mod.fused_act_call_count() > before
+    assert pallas_mod.kernel_call_counts()["fused_act_segment_matmul"] > before
     # ...and fuse_act_quant=False really does pin the two-pass form.
-    before = pallas_mod.fused_act_call_count()
+    before = pallas_mod.kernel_call_counts()["fused_act_segment_matmul"]
     smol.linear_apply(sp, x, QuantConfig(mode="serve", mix=qcfg.mix,
                                          backend="pallas_interpret",
                                          fuse_act_quant=False))
-    assert pallas_mod.fused_act_call_count() == before
+    assert pallas_mod.kernel_call_counts()["fused_act_segment_matmul"] \
+        == before
 
 
 @pytest.mark.parametrize("backend", BACKENDS)

@@ -459,10 +459,11 @@ def test_paged_op_backend_parity(kv_bits, window):
     cache, q, q_pos = _filled_paged(kv_bits)
     ref = registry.get("xla_ref").qkv_attn_decode_paged(q, cache, q_pos,
                                                         window=window)
-    before = pallas_backend.qkv_attn_paged_call_count()
+    before = pallas_backend.kernel_call_counts()["qkv_attn_decode_paged"]
     got = registry.get("pallas_interpret").qkv_attn_decode_paged(
         q, cache, q_pos, window=window)
-    dispatched = pallas_backend.qkv_attn_paged_call_count() - before
+    dispatched = pallas_backend.kernel_call_counts()[
+        "qkv_attn_decode_paged"] - before
     assert dispatched == (1 if kv_bits == 4 else 0)  # fp falls back
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
@@ -551,11 +552,12 @@ def test_paged_cross_backend_token_identity_and_dispatch(served):
     for name in ("xla_ref", "pallas_interpret"):
         eng = engine.DecodeEngine(params, cfg, _ecfg(
             backend=name, kv_bits=4, kv_layout="paged", page_size=4))
-        before = pallas_backend.qkv_attn_paged_call_count()
+        before = pallas_backend.kernel_call_counts()["qkv_attn_decode_paged"]
         got = {c.request_id: c.tokens
                for c in eng.serve(_mixed_requests(np.random.default_rng(1)))}
         outs[name] = {k - min(got): v for k, v in got.items()}
-        dispatched = pallas_backend.qkv_attn_paged_call_count() - before
+        dispatched = pallas_backend.kernel_call_counts()[
+            "qkv_attn_decode_paged"] - before
         assert dispatched == (0 if name == "xla_ref" else 2), dispatched
         eng.pool.check()
     assert set(outs["xla_ref"]) == set(outs["pallas_interpret"])
@@ -589,7 +591,7 @@ def test_paged_engine_prefix_sharing_and_occupancy(served):
     for k in ring_out:
         np.testing.assert_array_equal(ring_out[k], paged_out[k])
     paged_eng.pool.check()
-    stats = paged_eng.paged_kv_stats()
+    stats = paged_eng.kv_stats()
     assert stats["prefix_hits"] > 0
     ring_reserved = kv_quant.cache_payload_bytes(ring_eng.cache)
     assert stats["reserved_payload_bytes"] == ring_reserved

@@ -17,6 +17,7 @@ module that did so while being collected would leave the other test
 workers without it.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -53,11 +54,20 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _compile(fn, sharding, *avals):
+def _compile(name, fn, sharding, *avals):
+    """Compile ``fn`` for the described chip. Its kernel's custom call
+    carries the kernel's stable ``name``: the HLO instruction, after which
+    the profiler names the op, and the name scope of its ``pallas_call``,
+    whatever the wrappers around it are called."""
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
             for shape, dtype in avals]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    compiled = jax.jit(lambda *a: fn(*a)).lower(*args).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    for line in calls:
+        assert re.match(rf"\s*(ROOT )?%{name}(\.\d+)? = ", line), line
+        assert f"/{name}/pallas_call" in line, line
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
@@ -72,7 +82,8 @@ def _segment_avals(p, kp, n=D_MODEL):
 
 @pytest.mark.parametrize("p,kp", [(4, 1280), (2, 640), (1, 640)])
 def test_segment_gemm_compiles(one_chip, p, kp):
-    _compile(functools.partial(pm.packed_segment_matmul, p=p,
+    _compile("packed_segment_matmul",
+             functools.partial(pm.packed_segment_matmul, p=p,
                                interpret=False),
              one_chip, *_segment_avals(p, kp))
 
@@ -80,20 +91,23 @@ def test_segment_gemm_compiles(one_chip, p, kp):
 @pytest.mark.parametrize("p,kp", [(4, 1280), (2, 640), (1, 640)])
 def test_fused_gemm_compiles(one_chip, p, kp):
     x, wp, s = _segment_avals(p, kp)
-    _compile(functools.partial(pm.fused_act_segment_matmul, p=p,
+    _compile("fused_act_segment_matmul",
+             functools.partial(pm.fused_act_segment_matmul, p=p,
                                interpret=False),
              one_chip, x, ((M, 1), jnp.float32), wp, s)
 
 
 def test_selfscale_gemm_compiles(one_chip):
-    _compile(functools.partial(pm.fused_act_selfscale_matmul, p=4,
+    _compile("fused_act_selfscale_matmul",
+             functools.partial(pm.fused_act_selfscale_matmul, p=4,
                                interpret=False),
              one_chip, *_segment_avals(4, D_MODEL))
 
 
 def test_grouped_gemm_compiles_at_mixtral_expert_widths(one_chip):
     e, kp, n = 8, 6144, 16384
-    _compile(functools.partial(pm.packed_segment_matmul_grouped, p=4,
+    _compile("packed_segment_matmul_grouped",
+             functools.partial(pm.packed_segment_matmul_grouped, p=4,
                                interpret=False),
              one_chip, ((e, M, kp), jnp.float32),
              ((e, kp * 4 // 8, n), jnp.uint8), ((e, kp // 16), jnp.float32))
@@ -101,14 +115,15 @@ def test_grouped_gemm_compiles_at_mixtral_expert_widths(one_chip):
 
 @pytest.mark.parametrize("p", [4, 2, 1])
 def test_quantize_pack_compiles(one_chip, p):
-    _compile(functools.partial(qp.quantize_pack, p=p, interpret=False),
+    _compile("quantize_pack",
+             functools.partial(qp.quantize_pack, p=p, interpret=False),
              one_chip, ((D_MODEL, D_MODEL), jnp.float32),
              ((D_MODEL // 16,), jnp.float32))
 
 
 def test_noise_inject_compiles(one_chip):
-    _compile(lambda w, s, seed: ni.noise_inject(w, s, seed,
-                                                interpret=False),
+    _compile("noise_inject",
+             lambda w, s, seed: ni.noise_inject(w, s, seed, interpret=False),
              one_chip, ((D_MODEL, D_MODEL), jnp.float32),
              ((D_MODEL // 16,), jnp.float32), ((), jnp.uint32))
 
@@ -116,7 +131,8 @@ def test_noise_inject_compiles(one_chip):
 @pytest.mark.parametrize("row_scale", [True, False])
 def test_fake_quant_compiles(one_chip, row_scale):
     scale = (D_MODEL, 1) if row_scale else (D_MODEL // 16,)
-    _compile(functools.partial(fq.fake_quant, row_scale=row_scale,
+    _compile("fake_quant",
+             functools.partial(fq.fake_quant, row_scale=row_scale,
                                interpret=False),
              one_chip, ((D_MODEL, D_MODEL), jnp.float32),
              ((D_MODEL // 16,), jnp.float32), (scale, jnp.float32))
@@ -131,7 +147,8 @@ def test_ring_q4_decode_compiles(one_chip, s):
     b, t = RING_B, RING_T
     codes = ((b, t, HK, HD // 2), jnp.uint8)
     scale = ((b, t, HK, 1), jnp.float16)
-    _compile(functools.partial(ad.qkv_attn_decode, interpret=False),
+    _compile("qkv_attn_decode",
+             functools.partial(ad.qkv_attn_decode, interpret=False),
              one_chip, *_q_avals(b, s), codes, codes, scale, scale,
              ((b, t), jnp.int32), ((b, s), jnp.int32))
 
@@ -141,7 +158,8 @@ def test_paged_q4_decode_compiles(one_chip, s):
     b = RING_B
     codes = ((PAGES, PAGE, HK, HD // 2), jnp.uint8)
     scale = ((PAGES, PAGE, HK, 1), jnp.float16)
-    _compile(functools.partial(ad.qkv_attn_decode_paged, interpret=False),
+    _compile("qkv_attn_decode_paged",
+             functools.partial(ad.qkv_attn_decode_paged, interpret=False),
              one_chip, *_q_avals(b, s), codes, codes, scale, scale,
              ((PAGES, PAGE), jnp.int32), ((b, TABLE), jnp.int32),
              ((b, s), jnp.int32))

@@ -124,10 +124,10 @@ def test_qkv_attn_kernel_matches_oracle(window):
     cache, q, q_pos = _toy_cache_and_q()
     ref = registry.get("xla_ref").qkv_attn_decode(q, cache, q_pos,
                                                   window=window)
-    before = pallas_backend.qkv_attn_call_count()
+    before = pallas_backend.kernel_call_counts()["qkv_attn_decode"]
     got = registry.get("pallas_interpret").qkv_attn_decode(
         q, cache, q_pos, window=window)
-    assert pallas_backend.qkv_attn_call_count() == before + 1
+    assert pallas_backend.kernel_call_counts()["qkv_attn_decode"] == before + 1
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-6, atol=1e-6)
     assert np.isfinite(np.asarray(got)).all()
@@ -201,11 +201,12 @@ def test_kv4_cross_backend_token_identity(served):
                                    prefill_chunk=4, backend=name,
                                    kv_bits=4)
         eng = engine.DecodeEngine(params, cfg, ecfg)
-        before = pallas_backend.qkv_attn_call_count()
+        before = pallas_backend.kernel_call_counts()["qkv_attn_decode"]
         got = {c.request_id: c.tokens
                for c in eng.serve(_mixed_requests(np.random.default_rng(1)))}
         outs[name] = {k - min(got): v for k, v in got.items()}
-        dispatched = pallas_backend.qkv_attn_call_count() - before
+        dispatched = pallas_backend.kernel_call_counts()[
+            "qkv_attn_decode"] - before
         assert dispatched == (0 if name == "xla_ref" else 2), dispatched
     assert set(outs["xla_ref"]) == set(outs["pallas_interpret"])
     for k in outs["xla_ref"]:

@@ -127,13 +127,14 @@ def test_family_backend_parity(fam):
     cfg, params, enc_out, reqs = _setup(fam)
     ref = _baseline(fam)
 
-    before = pallas_backend.grouped_matmul_call_count()
+    grouped = "packed_segment_matmul_grouped"
+    before = pallas_backend.kernel_call_counts()[grouped]
     eng = engine.DecodeEngine(params, cfg,
                               _ecfg(backend="pallas_interpret"))
     got = _tokens_of(eng, reqs, enc_out)
 
     if fam == "moe":
-        assert pallas_backend.grouped_matmul_call_count() > before, \
+        assert pallas_backend.kernel_call_counts()[grouped] > before, \
             "MoE serve did not dispatch the grouped packed kernel"
     for name, ent in eng.jit_table.items():
         assert ent.trace_count <= 1, (name, ent.trace_count)

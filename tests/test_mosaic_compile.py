@@ -7,9 +7,10 @@ no chip time.
 
 Shapes are h2o-danube-1.8b's published widths (d_model 2560, 8 KV heads
 of 80, 4 query heads per KV head) split into the [K4|K2|K1] segments a
-50/25/25 mix gives, the decode kernels at ``chip_smoke.py``'s cache sizes,
-and the grouped GEMM at mixtral-8x22b's expert widths (d_model 6144,
-d_ff 16384, 8 experts).
+50/25/25 mix gives, the decode kernels at ``chip_smoke.py``'s cache sizes
+and the ring decode kernel at the chat benchmark cell's, and the grouped
+GEMM at mixtral-8x22b's expert widths (d_model 6144, d_ff 16384, 8
+experts).
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library, and a
@@ -36,6 +37,7 @@ M, D_MODEL = 8, 2560                   # decode rows, h2o-danube width
 HK, G, HD = 8, 4, 80                   # KV heads, queries per KV head, dim
 RING_B, RING_T = 4, 1024               # chip_smoke ring leg
 PAGES, PAGE, TABLE = 64, 16, 64        # chip_smoke paged leg
+CELL_B, CELL_T, WINDOW = 16, 512, 4096  # bench h2o-danube-1.8b.chat engine
 
 
 @pytest.fixture(scope="module")
@@ -149,6 +151,18 @@ def test_ring_q4_decode_compiles(one_chip, s):
     scale = ((b, t, HK, 1), jnp.float16)
     _compile("qkv_attn_decode",
              functools.partial(ad.qkv_attn_decode, interpret=False),
+             one_chip, *_q_avals(b, s), codes, codes, scale, scale,
+             ((b, t), jnp.int32), ((b, s), jnp.int32))
+
+
+@pytest.mark.parametrize("s", [1, 32])      # decode step, prefill chunk
+def test_ring_q4_decode_compiles_at_cell_shapes(one_chip, s):
+    b, t = CELL_B, CELL_T
+    codes = ((b, t, HK, HD // 2), jnp.uint8)
+    scale = ((b, t, HK, 1), jnp.float16)
+    _compile("qkv_attn_decode",
+             functools.partial(ad.qkv_attn_decode, interpret=False,
+                               window=WINDOW),
              one_chip, *_q_avals(b, s), codes, codes, scale, scale,
              ((b, t), jnp.int32), ((b, s), jnp.int32))
 

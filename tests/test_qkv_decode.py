@@ -2,19 +2,22 @@
 ``qkv_attn_decode`` backend op, engine/backend parity at ``kv_bits=4``,
 and the payload-byte accounting behind the "4x cache bytes" claim."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend import core as jcore
 
 from repro.backend import base as backend_base
 from repro.backend import pallas as pallas_backend
 from repro.backend import registry
 from repro.configs.base import ArchConfig
 from repro.core.qtypes import QuantConfig
+from repro.kernels import attn_decode_mod as attn_decode
 from repro.models import lm
-from repro.serve import engine, kv_quant
+from repro.serve import engine, kv_pool, kv_quant
 from repro.serve.scheduler import Request
 
 
@@ -111,17 +114,43 @@ def _toy_cache_and_q(seed=0, b=2, t=16, hk=2, d=32, g=2, s=3):
     cache = _fill_ring(kv_quant.init_qkv_cache(b, t, hk, d), key, b, hk, d,
                        range(10))
     q = jax.random.normal(jax.random.fold_in(key, 77), (b, s, hk, g, d))
-    q_pos = jnp.asarray([[7, 8, 9], [5, -1, 6]], jnp.int32)
+    q_pos = jnp.asarray([[7, 8, 9], [5, -1, 6]], jnp.int32)[:, :s]
     return cache, q, q_pos
 
 
-@pytest.mark.parametrize("window", [None, 4])
-def test_qkv_attn_kernel_matches_oracle(window):
-    """The Pallas flash-decode kernel (in-loop unpack + per-(slot, head)
-    scales) must match the dequantize-everything jnp oracle to fp32
-    tolerance, masked lanes and sliding window included — and must
-    actually dispatch (trace-time counter)."""
-    cache, q, q_pos = _toy_cache_and_q()
+def _toy_paged_and_q(seed=0, b=2, hk=2, d=80, g=2, s=3):
+    """A 4-bit page pool with slot 0's logical ring full and wrapped
+    (pages 1-4) and slot 1 on two pages; q at s query positions."""
+    cache = kv_pool.init_paged_cache(7, 4, 4, b, hk, d, kv_bits=4,
+                                     dtype=jnp.float32)
+    table = np.full((b, 4), -1, np.int32)
+    table[0] = [1, 2, 3, 4]
+    table[1, :2] = [5, 6]
+    cache["page_table"] = jnp.asarray(table)
+    key = jax.random.PRNGKey(seed)
+    for t in range(20):
+        kv = jax.random.normal(jax.random.fold_in(key, t), (b, 1, hk, d))
+        pos = jnp.asarray([t, t if t < 7 else -1], jnp.int32)
+        cache = kv_pool.update_paged_cache(cache, kv, -kv, pos)
+    q = jax.random.normal(jax.random.fold_in(key, 99), (b, s, hk, g, d))
+    q_pos = jnp.asarray([[18, -1, 19], [5, 6, -1]], jnp.int32)[:, :s]
+    return cache, q, q_pos
+
+
+_PARITY = pytest.mark.parametrize("window", [None, 4])
+_SHAPES = pytest.mark.parametrize("s", [1, 3])
+
+
+@_PARITY
+@_SHAPES
+@pytest.mark.parametrize("d", [32, 80, 128])
+def test_qkv_attn_kernel_matches_oracle(window, s, d):
+    """The Pallas flash-decode kernel (in-loop unpack into even and odd
+    channel halves + per-(slot, head) scales) must match the
+    dequantize-everything jnp oracle to fp32 tolerance at every head_dim
+    the registered models serve, masked lanes and sliding window
+    included — and must actually dispatch (trace-time counter)."""
+    cache, q, q_pos = _toy_cache_and_q(d=d, s=s)
     ref = registry.get("xla_ref").qkv_attn_decode(q, cache, q_pos,
                                                   window=window)
     before = pallas_backend.kernel_call_counts()["qkv_attn_decode"]
@@ -131,6 +160,93 @@ def test_qkv_attn_kernel_matches_oracle(window):
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-6, atol=1e-6)
     assert np.isfinite(np.asarray(got)).all()
+
+
+@_PARITY
+@_SHAPES
+def test_qkv_attn_paged_kernel_matches_oracle(window, s):
+    """The paged kernel shares the ring kernel's body: at head_dim 80 it
+    must match ``xla_ref``'s gather + dense oracle to the same fp32
+    tolerance, wrapped ring, unmapped pages and masked lanes included."""
+    cache, q, q_pos = _toy_paged_and_q(s=s)
+    ref = registry.get("xla_ref").qkv_attn_decode_paged(
+        q, cache, q_pos, window=window)
+    before = pallas_backend.kernel_call_counts()["qkv_attn_decode_paged"]
+    got = registry.get("pallas_interpret").qkv_attn_decode_paged(
+        q, cache, q_pos, window=window)
+    assert pallas_backend.kernel_call_counts()[
+        "qkv_attn_decode_paged"] == before + 1
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=1e-6, atol=1e-6)
+    assert np.isfinite(np.asarray(got)).all()
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for x in (v if isinstance(v, (tuple, list)) else [v]):
+            if isinstance(x, jcore.ClosedJaxpr):
+                yield x.jaxpr
+            elif isinstance(x, jcore.Jaxpr):
+                yield x
+
+
+def _eqns(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for sub in _sub_jaxprs(e):
+            yield from _eqns(sub)
+
+
+def _moves_lanes(eqn):
+    """An op that moves data across lanes: a concatenate, or a reshape or
+    broadcast whose last (lane) dimension changes between two extents
+    above 1. A scalar splat or a keepdims column ([SG] -> [SG, 1], the
+    softmax's running max and sum) does not."""
+    if eqn.primitive.name == "concatenate":
+        return True
+    if eqn.primitive.name not in ("reshape", "broadcast_in_dim"):
+        return False
+    src, dst = eqn.invars[0].aval.shape, eqn.outvars[0].aval.shape
+    return bool(src) and min(src[-1], dst[-1]) > 1 and src[-1] != dst[-1]
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_tile_loop_moves_no_lanes(paged):
+    """Guard on the kernels' tile loop at head_dim 80: interleaving the
+    nibbles back into channel order inside the loop (a stack and reshape
+    to [bt, D]) lowered through Mosaic to some 38k lane and sublane
+    shuffles per program. The loop must keep the channel halves as the
+    carrier stores them."""
+    b, s, t, hk, g, d = 2, 3, 512, 2, 2, 80
+    q = jax.ShapeDtypeStruct((b, s, hk, g, d), jnp.float32)
+    q_pos = jax.ShapeDtypeStruct((b, s), jnp.int32)
+    if paged:
+        p, ps = 8, 64
+        codes = jax.ShapeDtypeStruct((p, ps, hk, d // 2), jnp.uint8)
+        scale = jax.ShapeDtypeStruct((p, ps, hk, 1), jnp.float16)
+        fn = functools.partial(attn_decode.qkv_attn_decode_paged,
+                               interpret=False, window=4)
+        args = (q, codes, codes, scale, scale,
+                jax.ShapeDtypeStruct((p, ps), jnp.int32),
+                jax.ShapeDtypeStruct((b, t // ps), jnp.int32), q_pos)
+    else:
+        codes = jax.ShapeDtypeStruct((b, t, hk, d // 2), jnp.uint8)
+        scale = jax.ShapeDtypeStruct((b, t, hk, 1), jnp.float16)
+        fn = functools.partial(attn_decode.qkv_attn_decode,
+                               interpret=False, window=4)
+        args = (q, codes, codes, scale, scale,
+                jax.ShapeDtypeStruct((b, t), jnp.int32), q_pos)
+    calls = [e for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    loops = [e for e in _eqns(calls[0].params["jaxpr"])
+             if e.primitive.name in ("scan", "while")]
+    assert len(loops) == 1
+    body = [e for sub in _sub_jaxprs(loops[0]) for e in _eqns(sub)]
+    assert any(e.primitive.name == "dot_general" for e in body)
+    moved = [f"{e.primitive.name} {e.invars[0].aval.shape} -> "
+             f"{e.outvars[0].aval.shape}" for e in body if _moves_lanes(e)]
+    assert not moved, moved
 
 
 def test_qkv_attn_oracle_matches_fp_attention_closely():

@@ -3,10 +3,12 @@
 Everything that belongs to one configuration, traffic mix, per-layer
 metric or kernel lives in a file of its own, named after it:
 
-    bench/configs/<config>.json      sizes, quantization, source
+    bench/configs/<config>.json      sizes, quantization, source; its
+                                     CPU test cut (``tiny``)
     bench/traffic/<mix>.json         driver, lengths, rate,
                                      engine settings
     bench/checks/<cell>.json         the correctness comparison's limit
+                                     (and the test cut's, ``tiny_limit``)
     bench/drivers/<driver>.py        a serve loop (``run(session, ...)``)
     bench/metrics/<metric>.py        ``read(run) -> float | None``
     bench/work/<kernel>.py           trace-name patterns, ops and bytes
@@ -20,7 +22,7 @@ import importlib.util
 import json
 from pathlib import Path
 from types import ModuleType
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
@@ -77,9 +79,9 @@ def _metric(entry: Dict) -> Metric:
                   entry.get("layer", ""))
 
 
-def load_cell(name: str, manifest_path: Path = ROOT / "BENCHMARK.json"
-              ) -> Cell:
-    manifest = load_json(manifest_path)
+def load_cell(name: str, manifest_path: Optional[Path] = None) -> Cell:
+    """The cell ``name`` of ``manifest_path`` (``ROOT/BENCHMARK.json``)."""
+    manifest = load_json(manifest_path or ROOT / "BENCHMARK.json")
     cells = {w["name"]: w for w in manifest["workloads"]}
     if name not in cells:
         raise KeyError(f"unknown workload {name!r}; have {sorted(cells)}")

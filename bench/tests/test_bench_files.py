@@ -6,6 +6,7 @@ import re
 import pytest
 
 from benchlib import cell as cell_lib
+from benchlib import flops
 
 MANIFEST = cell_lib.load_json(cell_lib.ROOT / "BENCHMARK.json")
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
@@ -54,31 +55,72 @@ def test_every_named_file_exists():
         assert w.PATTERNS and callable(w.step_work)
 
 
+# A published config.json key and the ``model`` key it gives, compared
+# where the file states it. A configuration that holds part of each MoE
+# layer's experts states the count held under the published count's key.
+PUBLISHED = {"hidden_size": "d_model", "intermediate_size": "d_ff",
+             "num_attention_heads": "num_heads",
+             "num_key_value_heads": "num_kv_heads",
+             "num_hidden_layers": "num_layers", "vocab_size": "vocab_size",
+             "num_local_experts": "experts_held",
+             "num_experts": "experts_held", "num_experts_per_tok": "top_k",
+             "mamba_d_state": "ssm_state", "mamba_expand": "ssm_expand",
+             "mamba_d_head": "ssm_head_dim"}
+
+
+def program_value(cfg, key: str):
+    """The program's value of a ``model`` key: the ``ArchConfig`` field of
+    that name, but for the few the program keeps elsewhere."""
+    from repro.models import ssm
+    elsewhere = {"head_dim": cfg.hd, "mix": list(cfg.quant.mix),
+                 "ssm_head_dim": ssm.HEAD_DIM,
+                 "experts_held": getattr(cfg, "experts_held",
+                                         cfg.num_experts)}
+    if key in elsewhere:
+        return elsewhere[key]
+    assert hasattr(cfg, key), f"the program's ArchConfig has no {key!r}"
+    got = getattr(cfg, key)
+    return list(got) if isinstance(got, tuple) else got
+
+
+def check_published(conf):
+    """The file's published-style keys, as run, agree with its ``model``
+    keys (the reference's), and each key it cut differs from the
+    published value."""
+    m = conf["model"]
+    for pub, key in PUBLISHED.items():
+        if pub in conf:
+            assert conf[pub] == flops.value(m, key), (pub, key)
+    if "head_dim" in m:
+        assert m["head_dim"] == (conf.get("head_dim") or
+                                 conf["hidden_size"]
+                                 // conf["num_attention_heads"])
+    for key in conf["reduced"]:
+        assert conf["published"][key] != conf[key]
+
+
+def check_program(conf):
+    """The program's ``ArchConfig`` as the benchmark builds it agrees with
+    the file key by key: every key of its ``model`` dict, and every layout
+    key the work counts read (``flops.LAYOUT``), stated or not."""
+    from benchlib import system
+    m = conf["model"]
+    cfg = system.arch_config(conf)
+    for key in sorted(set(m) | set(flops.LAYOUT)):
+        assert program_value(cfg, key) == flops.value(m, key), key
+    assert cfg.tie_embeddings == conf["tie_word_embeddings"]
+    assert cfg.dtype == conf["precision"]
+    assert cfg.quant.quantize_activations is False
+
+
+def check_config(conf):
+    check_published(conf)
+    check_program(conf)
+
+
 @pytest.mark.parametrize("name", [c["name"] for c in MANIFEST["configs"]])
 def test_config_file_matches_the_program(name):
-    from benchlib import system
     entry = {c["name"]: c for c in MANIFEST["configs"]}[name]
     conf = cell_lib.load_json(cell_lib.ROOT / entry["file"])
     assert conf["reduced"] == entry["reduced"]
-    m = conf["model"]
-    # The file's published-style keys, as run, and the reference's keys.
-    assert conf["hidden_size"] == m["d_model"]
-    assert conf["intermediate_size"] == m["d_ff"]
-    assert conf["num_attention_heads"] == m["num_heads"]
-    assert conf["num_key_value_heads"] == m["num_kv_heads"]
-    assert conf["num_hidden_layers"] == m["num_layers"]
-    assert conf["vocab_size"] == m["vocab_size"]
-    assert m["head_dim"] == m["d_model"] // m["num_heads"]
-    for key in conf["reduced"]:
-        assert conf["published"][key] != conf[key]
-    # ... and the program's ArchConfig as the benchmark builds it.
-    cfg = system.arch_config(conf)
-    assert (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
-            cfg.hd, cfg.d_ff, cfg.vocab_size, cfg.window, cfg.norm,
-            cfg.norm_eps, cfg.mlp_act, cfg.rope_theta, list(cfg.quant.mix)
-            ) == (m["num_layers"], m["d_model"], m["num_heads"],
-                  m["num_kv_heads"], m["head_dim"], m["d_ff"],
-                  m["vocab_size"], m["window"], m["norm"], m["norm_eps"],
-                  m["mlp_act"], m["rope_theta"], m["mix"])
-    assert cfg.dtype == conf["precision"] and not cfg.tie_embeddings
-    assert cfg.quant.quantize_activations is False
+    check_config(conf)

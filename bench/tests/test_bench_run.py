@@ -23,25 +23,29 @@ RUN = cell_lib.BENCH / "run.py"
 # 1, 2, 3, 2**31 + 5, 77, on the CPU, danube cut to TINY): the program
 # read 0.0125 to 0.0157 on both CPU backends, the float8 control 0.140 to
 # 0.188. The cells' own limits, at their sizes, are in
-# ``bench/checks/<cell>.json``.
+# ``bench/checks/<cell>.json``; a check file's ``tiny_limit`` replaces
+# this one for its cell, and a configuration's ``tiny`` keys replace TINY.
 TINY_LIMIT = 0.05
 TINY = {"num_layers": 2, "d_model": 128, "num_heads": 4, "num_kv_heads": 2,
         "head_dim": 32, "d_ff": 256, "vocab_size": 256}
 
 
 def tiny_cell(name: str):
-    """The cell at a size a test run holds: every width cut, the cell's
-    own mix, driver and reference, the test size's limit."""
+    """The cell at a size a test run holds: the configuration's test cut
+    (its ``tiny`` keys, else TINY with no window) over its own overrides,
+    the cell's own mix, driver and reference, the test size's limit (the
+    check file's ``tiny_limit``, else TINY_LIMIT)."""
     cell = copy.deepcopy(cell_lib.load_cell(name))
-    cell.config["overrides"] = dict(TINY, window=None)
-    cell.config["model"].update(TINY, window=None)
+    tiny = cell.config.get("tiny", dict(TINY, window=None))
+    cell.config["overrides"] = {**cell.config.get("overrides", {}), **tiny}
+    cell.config["model"].update(tiny)
     cell.traffic["engine"].update(max_batch=4, cache_len=128,
                                   prefill_chunk=8)
     cell.traffic["prompt_len"].update(median=16, min=4, max=48)
     cell.traffic["output_len"].update(median=8, min=4, max=32)
     if "rate_rps" in cell.traffic:
         cell.traffic["rate_rps"] = 8.0
-    cell.check["limit"] = TINY_LIMIT
+    cell.check["limit"] = cell.check.get("tiny_limit", TINY_LIMIT)
     return cell
 
 

@@ -80,6 +80,35 @@ def test_prefill_step_flops_read_out_one_row_per_slot():
     assert f == 2 * 2 * lin + 2 * 2560 * 32000 + attn
 
 
+# What the counts read before they followed the layer kinds (literals from
+# that code), at a decode step of 16 slots and a mixed prefill step with a
+# context past danube's window: a dense decoder's counts must not move.
+_DECODE = [(100 + 37 * i, 1) for i in range(16)], 16
+_PREFILL = [(0, 32), (64, 32), (4090, 32), (300, 1), (5000, 1)], 5
+
+
+@pytest.mark.parametrize("model,step,model_flops,linear,attention", [
+    (DANUBE, _DECODE, 57461309440.0, (53351546880.0, 651397632.0),
+     (1488322560.0, 105535488.0)),
+    (DANUBE, _PREFILL, 361649438720.0, (326778224640.0, 918415872.0),
+     (34052014080.0, 187208448.0)),
+    (STARCODER, _DECODE, 63706890240.0, (55566139392.0, 651772928.0),
+     (892993536.0, 30299136.0)),
+    (STARCODER, _PREFILL, 363223941120.0, (340342603776.0, 793510912.0),
+     (20616413184.0, 69249024.0)),
+], ids=["danube-decode", "danube-prefill", "starcoder-decode",
+        "starcoder-prefill"])
+def test_dense_counts_are_pinned(model, step, model_flops, linear,
+                                 attention):
+    rec = _step(*step)
+    assert flops.model_step_flops(model, rec) == model_flops
+    assert cell_lib.load_module("work", "packed_linear").step_work(
+        model, rec) == linear
+    assert cell_lib.load_module("work", "attn_decode").step_work(
+        model, rec) == attention
+    assert flops.attention_flops(model, rec.fed) == attention[0]
+
+
 def test_peaks_table_knows_v5e_and_refuses_others():
     p = cell_lib.peaks("TPU v5 lite")
     assert p["flops_per_s"] == 197e12 and p["bytes_per_s"] == 819e9

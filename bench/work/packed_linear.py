@@ -4,7 +4,9 @@ every layer, one kernel call per non-empty [K4|K2|K1] segment.
 
 Work of one step: 2 * tokens * K * N FLOPs per linear, and the bytes of
 the packed weights (read once per step), the activations in (bfloat16)
-and the float32 outputs of the fed tokens. The least time of a step is
+and the float32 outputs of the fed tokens. Only the linears on this
+kernel's path count: not the router, nor the stacked expert banks, which
+``packed_segment_matmul_grouped`` runs. The least time of a step is
 the larger of FLOPs over the peak and bytes over the bandwidth: decode
 steps are bound by the bytes, prefill-width steps by the FLOPs."""
 from benchlib import flops
@@ -20,8 +22,11 @@ def step_work(m, step):
     """(FLOPs, bytes) the step's fed tokens need of the segment GEMMs."""
     tokens = sum(w for _, w in step.fed)
     f = b = 0.0
-    for _, k, n in flops.linears(m):
-        f += 2.0 * tokens * k * n
-        b += flops.packed_weight_bytes(k, n, m["mix"]) \
-            + tokens * k * 2 + tokens * n * 4
-    return f * m["num_layers"], b * m["num_layers"]
+    for x in flops.linears(m):
+        if x.kernel != "segment":
+            continue
+        rows = tokens * x.share
+        f += 2.0 * rows * x.k * x.n * x.copies
+        b += (flops.packed_weight_bytes(x.k, x.n, m["mix"])
+              + rows * x.k * 2 + rows * x.n * 4) * x.copies
+    return f, b
